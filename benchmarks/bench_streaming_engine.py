@@ -133,7 +133,6 @@ def run_rerank(n_queries: int = 2048, cmax: int = 256,
 
     from repro.core import engine as E
     from repro.core import retrieval as R
-    from repro.distributed import compat
 
     rng = np.random.default_rng(seed)
     vocab = 64
@@ -172,7 +171,9 @@ def run_rerank(n_queries: int = 2048, cmax: int = 256,
             enc, k=k, query_ids=qids, doc_ids=dids, per_query=per_query,
             store=store),
         "rerank_sharded": E.ShardedStreamRerankStage(
-            enc, compat.make_mesh((jax.device_count(),), ("data",)), k=k,
+            enc, jax.make_mesh((jax.device_count(),), ("data",),
+                               axis_types=(jax.sharding.AxisType.Auto,)),
+            k=k,
             query_ids=qids, doc_ids=dids, per_query=per_query, store=store),
     }
 

@@ -23,13 +23,13 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.retrieval import topk_exact, topk_sharded
-from repro.distributed import compat
 from repro.distributed.fault import run_chunked
 
 
 def main():
     assert len(jax.devices()) == 8, "expected 8 simulated devices"
-    mesh = compat.make_mesh((4, 2), ("data", "model"))
+    mesh = jax.make_mesh((4, 2), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
     rng = np.random.default_rng(0)
     Q, N, D, k = 16, 40_000, 64, 100
     q = jnp.asarray(rng.normal(size=(Q, D)), jnp.float32)

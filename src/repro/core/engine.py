@@ -68,7 +68,6 @@ from repro.core.retrieval import (_hierarchical_slot_max,
                                   pad_candidates, rank_candidates, rerank_run,
                                   retrieve_run)
 from repro.data.corpus import Tokens, pad_batch
-from repro.distributed import compat
 
 Run = Dict[str, List[str]]
 Scores = Dict[str, List[float]]
@@ -410,12 +409,20 @@ def _sharded_encoder(encode_fn: Callable, mesh,
     ax = axis_names[0] if len(axis_names) == 1 else axis_names
 
     def build():
-        return jax.jit(compat.shard_map(
+        return jax.jit(jax.shard_map(
             encode_fn, mesh=mesh, in_specs=(P(), P(ax), P(ax)),
-            out_specs=P(ax), check=False))
+            out_specs=P(ax), check_vma=False))
 
     return cached_compiled(_SHARDED_ENC_CACHE, (encode_fn, mesh, axis_names),
                            build)
+
+
+def place_params(params, mesh=None):
+    """``params`` on the default device, or replicated over ``mesh``."""
+    if mesh is None:
+        return jax.device_put(params)
+    from repro.distributed.sharding import replicated_sharding
+    return jax.device_put(params, replicated_sharding(mesh))
 
 
 def encode_store(encode_fn: Callable, params, store: TokenStore, *,
@@ -684,13 +691,13 @@ class ShardedStreamTopKStage(StreamTopKStage):
             return _merge_topk(run_s, run_i, bs, bi, k_carry)
 
         spec_rows = P(ax)
-        # check=False: the carry is replicated-in, device-varying mid-step,
-        # re-replicated by the final merge — same legal pattern topk_sharded
-        # documents.
-        self._fused = jax.jit(compat.shard_map(
+        # check_vma=False: the carry is replicated-in, device-varying
+        # mid-step, re-replicated by the final merge — same legal pattern
+        # topk_sharded documents.
+        self._fused = jax.jit(jax.shard_map(
             local, mesh=mesh,
             in_specs=(P(), P(), P(), P(), spec_rows, spec_rows, P(), P()),
-            out_specs=(P(), P()), check=False))
+            out_specs=(P(), P()), check_vma=False))
         # layout staged token chunks must be device_put with so the step's
         # in_specs find them already resident (no re-layout at dispatch)
         from repro.distributed.sharding import rows_sharding
@@ -900,14 +907,14 @@ class ShardedStreamRerankStage(StreamRerankStage):
             return _hierarchical_slot_max(part, axis_names)
 
         spec_rows = P(ax)
-        # check=False: the carry enters replicated, is device-varying after
-        # the per-shard slot writes, and is re-replicated by the final merge
-        # — the same legal pattern ShardedStreamTopKStage documents.
-        self._fused = jax.jit(compat.shard_map(
+        # check_vma=False: the carry enters replicated, is device-varying
+        # after the per-shard slot writes, and is re-replicated by the final
+        # merge — the same legal pattern ShardedStreamTopKStage documents.
+        self._fused = jax.jit(jax.shard_map(
             local, mesh=mesh,
             in_specs=(P(), P(), P(), P(), spec_rows, spec_rows, spec_rows,
                       P(), P()),
-            out_specs=P(), check=False), donate_argnums=_donate(2,))
+            out_specs=P(), check_vma=False), donate_argnums=_donate(2,))
         from repro.distributed.sharding import replicated_sharding, \
             rows_sharding
         # staged token chunks (and the per-chunk row masks) land pre-sharded;
@@ -1082,6 +1089,10 @@ class StreamingEngine:
 
     def run(self, params) -> Tuple[Run, Scores, Dict[str, float]]:
         tel = self.telemetry
+        # place the checkpoint once: host arrays (a restored checkpoint)
+        # handed to every jitted dispatch are copied to the device per
+        # dispatch, and queued dispatches keep all those copies alive
+        params = place_params(params, self.query_mesh)
         t0 = time.time()
         m0 = time.monotonic() if tel is not None else 0.0
         q_emb = encode_store(self.spec.encode_query, params, self.query_store,

@@ -26,7 +26,6 @@ import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from repro.core.precision import chunk_scores, validate_score_dtype
-from repro.distributed import compat
 
 
 def _merge_topk(scores_a, idx_a, scores_b, idx_b, k: int):
@@ -141,13 +140,13 @@ def topk_sharded(mesh, q_emb, c_emb, *, k: int, axis_names=("data", "model"),
         return _hierarchical_topk_merge(s, i, axis_names, k)
 
     spec_c = P(axis_names if len(axis_names) > 1 else axis_names[0])
-    # check=False (check_vma/check_rep): the inner lax.scan carry starts
-    # replicated and becomes device-varying after the first block — a legal
-    # pattern the varying-manual-axes checker can't type; outputs are
-    # re-replicated by the final merge anyway.
-    fn = compat.shard_map(local, mesh=mesh,
-                          in_specs=(P(), spec_c),
-                          out_specs=(P(), P()), check=False)
+    # check_vma=False: the inner lax.scan carry starts replicated and
+    # becomes device-varying after the first block — a legal pattern the
+    # varying-manual-axes checker can't type; outputs are re-replicated by
+    # the final merge anyway.
+    fn = jax.shard_map(local, mesh=mesh,
+                       in_specs=(P(), spec_c),
+                       out_specs=(P(), P()), check_vma=False)
     return fn(q_emb, c_emb)
 
 

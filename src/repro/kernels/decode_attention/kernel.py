@@ -25,7 +25,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams
 
 _LANES = 128
 _NEG_INF = -1e30
@@ -106,7 +105,7 @@ def decode_attention_kernel(length, q, k, v, *, bk: int = 512,
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((B, KV, G, d), q.dtype),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(length, q, k, v)

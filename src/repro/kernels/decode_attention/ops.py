@@ -18,7 +18,7 @@ def decode_attention(q, k, v, length, *, bk: int = 512,
 
     Returns (B, KV, G, d) in q.dtype."""
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = jax.default_backend() == "cpu"
     B, KV, G, d = q.shape
     T = k.shape[2]
     bk = min(bk, _pad_to(T, 128))

@@ -11,8 +11,8 @@ SIMD registers; the TPU-native rethink is:
   * the per-query *running top-k* (scores + global indices) lives in VMEM
     scratch across the whole corpus sweep — candidates never round-trip to
     HBM per tile (the FAISS heap equivalent, kept on-chip);
-  * the merge is ``top_k([running ‖ tile_scores])`` — a tournament merge on
-    the VPU, amortized against the MXU matmul;
+  * the merge is the top-k of ``[running ‖ tile_scores]``, taken by ``k``
+    rounds of max-extraction on the VPU (Mosaic lowers no sort);
   * grid = (q_tiles, corpus_tiles), corpus innermost ("arbitrary"
     semantics — the running top-k is carried across corpus steps; q tiles
     are embarrassingly parallel).
@@ -45,7 +45,55 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams
+
+_LOWEST = float(jnp.finfo(jnp.float32).min)
+_NO_POS = 2 ** 30
+
+
+def _merge_tile(run_s, run_i, scores, col, k: int):
+    """Fold one tile into the running top-k: top-k of [carry ‖ tile].
+
+    Mosaic lowers no sort or ``top_k``, so the merge is ``k`` rounds of
+    max-extraction on the VPU.  Each round takes the row max, picks the
+    lowest position holding it in the concatenated order (``lax.top_k``'s
+    tie order), writes it to output slot ``j`` and retires that position by
+    setting its key to ``-inf``.  Real ``-inf`` scores (padding, the empty
+    carry) enter as the lowest finite float so a retired slot is never
+    picked twice, and leave as ``-inf`` again.
+    """
+    bq, bn = scores.shape
+    pos_r = jax.lax.broadcasted_iota(jnp.int32, (bq, k), 1)
+    pos_t = jax.lax.broadcasted_iota(jnp.int32, (bq, bn), 1) + k
+    cur_i = run_i[...]
+
+    def finite(x):
+        return jnp.where(x == -jnp.inf, _LOWEST, x)
+
+    def body(j, carry):
+        kr, kt, out_s, out_i = carry
+        m = jnp.maximum(jnp.max(kr, axis=1, keepdims=True),
+                        jnp.max(kt, axis=1, keepdims=True))
+        p = jnp.minimum(
+            jnp.min(jnp.where(kr == m, pos_r, _NO_POS), axis=1,
+                    keepdims=True),
+            jnp.min(jnp.where(kt == m, pos_t, _NO_POS), axis=1,
+                    keepdims=True))
+        idx = (jnp.sum(jnp.where(pos_r == p, cur_i, 0), axis=1,
+                       keepdims=True)
+               + jnp.sum(jnp.where(pos_t == p, col, 0), axis=1,
+                         keepdims=True))
+        here = pos_r == j
+        out_s = jnp.where(here, jnp.where(m == _LOWEST, -jnp.inf, m), out_s)
+        out_i = jnp.where(here, idx, out_i)
+        return (jnp.where(pos_r == p, -jnp.inf, kr),
+                jnp.where(pos_t == p, -jnp.inf, kt), out_s, out_i)
+
+    _, _, top_s, top_i = jax.lax.fori_loop(
+        0, k, body, (finite(run_s[...]), finite(scores),
+                     jnp.zeros((bq, k), jnp.float32),
+                     jnp.zeros((bq, k), jnp.int32)))
+    run_s[...] = top_s
+    run_i[...] = top_i
 
 
 def _mips_kernel(q_ref, c_ref, out_s_ref, out_i_ref, run_s, run_i, *,
@@ -74,12 +122,7 @@ def _mips_kernel(q_ref, c_ref, out_s_ref, out_i_ref, run_s, run_i, *,
     valid = col < n_total                       # mask corpus padding rows
     scores = jnp.where(valid, scores, -jnp.inf)
 
-    # tournament merge: top-k of [running candidates ‖ this tile]
-    merged_s = jnp.concatenate([run_s[...], scores], axis=1)
-    merged_i = jnp.concatenate([run_i[...], col], axis=1)
-    top_s, pos = jax.lax.top_k(merged_s, k)
-    run_s[...] = top_s
-    run_i[...] = jnp.take_along_axis(merged_i, pos, axis=1)
+    _merge_tile(run_s, run_i, scores, col, k)
 
     @pl.when(ci == n_ctiles - 1)
     def _flush():
@@ -118,11 +161,7 @@ def _mips_kernel_int8(q_ref, c_ref, qs_ref, cs_ref, out_s_ref, out_i_ref,
     valid = col < n_total                       # mask corpus padding rows
     scores = jnp.where(valid, scores, -jnp.inf)
 
-    merged_s = jnp.concatenate([run_s[...], scores], axis=1)
-    merged_i = jnp.concatenate([run_i[...], col], axis=1)
-    top_s, pos = jax.lax.top_k(merged_s, k)
-    run_s[...] = top_s
-    run_i[...] = jnp.take_along_axis(merged_i, pos, axis=1)
+    _merge_tile(run_s, run_i, scores, col, k)
 
     @pl.when(ci == n_ctiles - 1)
     def _flush():
@@ -165,7 +204,7 @@ def topk_mips_kernel(q: jnp.ndarray, c: jnp.ndarray, *, k: int,
             pltpu.VMEM((bq, k), jnp.float32),
             pltpu.VMEM((bq, k), jnp.int32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(q, c)
@@ -214,7 +253,7 @@ def topk_mips_kernel_int8(q: jnp.ndarray, c: jnp.ndarray,
             pltpu.VMEM((bq, k), jnp.float32),
             pltpu.VMEM((bq, k), jnp.int32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(q, c, q_scale, c_scale)

@@ -1,9 +1,10 @@
 """jit'd dispatch wrapper for the topk_mips Pallas kernel.
 
 Handles shape padding (queries to bq, corpus rows to bn, feature dim to the
-128-lane MXU width) and backend selection: on TPU the Mosaic kernel runs
-natively; everywhere else (this CPU box) ``interpret=True`` executes the
-kernel body in Python for correctness validation.
+128-lane MXU width) and backend selection: on the CPU backend
+``interpret=True`` executes the kernel body in Python for correctness
+validation; on any other backend the Mosaic kernel compiles natively or
+raises.
 """
 
 from __future__ import annotations
@@ -56,7 +57,7 @@ def topk_mips(q: jnp.ndarray, c: jnp.ndarray, *, k: int, bq: int = 128,
     in before the f32 carry merge — a quarter of the tile bytes).
     """
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = jax.default_backend() == "cpu"
     if score_dtype == "f32":
         return _topk_mips_jit(q, c, k=k, bq=bq, bn=bn, interpret=interpret,
                               n_valid=n_valid)

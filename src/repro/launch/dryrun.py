@@ -1,6 +1,3 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-
 """Multi-pod dry-run: lower + compile every (arch x shape) on the production
 meshes and extract the roofline terms (no device allocation — all inputs are
 ShapeDtypeStructs).
@@ -17,28 +14,24 @@ Usage:
   python -m repro.launch.dryrun --arch qwen2-72b --shape train_4k
   python -m repro.launch.dryrun --all [--jobs 4]     # every cell, subprocesses
   python -m repro.launch.dryrun --report             # aggregate JSON -> table
+
+A CPU-only tool: run as a program it pins JAX to the CPU with 512 host
+devices.  Nothing on the chip path imports it.
 """
 
 import argparse
 import json
+import os
 import subprocess
 import sys
 import time
 import traceback
 
 
-def _enable_compile_cache():
-    import jax
-    jax.config.update("jax_compilation_cache_dir", "/tmp/jax_cache")
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 2)
-    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-
-
 def _run_cell(arch: str, shape: str, out_dir: str, *, skip_multipod: bool,
               mesh_override=None, knobs=None, tag: str = "") -> dict:
     # imports deferred: jax must init after XLA_FLAGS (512 host devices)
     import jax
-    _enable_compile_cache()
     from repro.launch import analysis
     from repro.launch.mesh import make_production_mesh
     from repro.launch.steps import build_step
@@ -299,4 +292,9 @@ def main() -> int:
 
 
 if __name__ == "__main__":
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+    import jax
+    jax.config.update("jax_platforms", "cpu")
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     sys.exit(main())

@@ -24,12 +24,18 @@ Two pieces:
 
 See ``examples/fleet_validation.py`` for the full walkthrough: 1 trainer +
 2 heterogeneous workers + control plane.
+
+One process per TPU host: libtpu lets a single process hold a host's chips,
+so where workers would reach TPU chips the pool starts at most one worker
+there (give it a validator mesh over every chip).  The supervisor itself
+never starts a JAX backend.
 """
 
 from __future__ import annotations
 
 import argparse
 import dataclasses
+import os
 import subprocess
 import sys
 import time
@@ -50,6 +56,16 @@ class WorkerProc:
     restarts: int = 0
 
 
+def host_tpu_chips() -> int:
+    """TPU chips the workers would reach, counted on the PCI bus without
+    starting a JAX backend; 0 when ``JAX_PLATFORMS`` leaves TPUs out."""
+    platforms = os.environ.get("JAX_PLATFORMS", "")
+    if platforms and "tpu" not in platforms.split(","):
+        return 0
+    from jax._src import hardware_utils
+    return hardware_utils.num_available_tpu_chips_and_device_id()[0]
+
+
 class LocalWorkerPool:
     """Spawns and supervises local worker subprocesses."""
 
@@ -60,7 +76,14 @@ class LocalWorkerPool:
               id_prefix: str = "worker") -> List[WorkerProc]:
         """Spawn ``n`` workers running ``base_argv`` with distinct
         ``--worker_id``\\ s appended (``repro.core.cli --worker`` reads it;
-        custom workers are free to ignore it)."""
+        custom workers are free to ignore it).  Raises ``RuntimeError``
+        rather than start a second worker on a host with TPU chips."""
+        chips = host_tpu_chips()
+        if chips and len(self.workers) + n > 1:
+            raise RuntimeError(
+                f"{len(self.workers) + n} workers requested on a host with "
+                f"{chips} TPU chip(s): one process holds a host's chips, so "
+                "run one worker here with a validator mesh over all of them")
         spawned = []
         for i in range(len(self.workers), len(self.workers) + n):
             wid = f"{id_prefix}-{i}"
@@ -269,7 +292,10 @@ def main(argv=None) -> int:
     # supervision only: CLI workers discover + publish units themselves
     # (publication is idempotent), so no ledger path is needed here
     pool = LocalWorkerPool()
-    pool.spawn(base, args.workers)
+    try:
+        pool.spawn(base, args.workers)
+    except RuntimeError as e:
+        ap.error(str(e))
     print(f"[fleet] {args.workers} workers spawned", file=sys.stderr)
     try:
         while pool.alive():

@@ -17,13 +17,12 @@ from __future__ import annotations
 
 import jax
 
-from repro.distributed import compat
-
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return compat.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_validator_mesh(n_devices: int | None = None, *, model_axis: int = 1):

@@ -1,12 +1,15 @@
 """End-to-end training driver with asynchronous checkpoint validation.
 
 The Asyncval deployment (paper Fig. 1b): the trainer commits checkpoints to
-a directory; a decoupled validator (its own mesh — on this box a thread over
-the disaggregated device halves) watches the directory and validates each
-checkpoint while training continues.  Training NEVER blocks on validation.
+a directory; a decoupled validator thread watches the directory and
+validates each checkpoint while training continues.  Training NEVER blocks
+on validation.  Both run on the process's default device: on a multi-chip
+host the trainer and the validator share the first chip.
 
     python -m repro.launch.train --arch dr-bert-base --steps 60 \
-        --ckpt-every 10 --workdir /tmp/asyncval_run [--sync]
+        --ckpt-every 10 --workdir asyncval_run [--sync] [--write-run]
+
+The exit code is 1 when any checkpoint failed to validate.
 
 ``--sync`` runs the paper's Figure-1a baseline instead (validation inline
 in the training loop) so the wall-clock pipelining win is measurable —
@@ -23,6 +26,7 @@ import argparse
 import dataclasses
 import json
 import os
+import sys
 import time
 
 import jax
@@ -133,7 +137,9 @@ def run(args) -> dict:
 
     sampler = (RunFileTopK(depth=args.depth) if args.subset else FullCorpus())
     vcfg = ValidationConfig(metrics=("MRR@10", "Recall@100"),
-                            k=100, batch_size=args.batch_size)
+                            k=100, batch_size=args.batch_size,
+                            write_run=getattr(args, "write_run", False),
+                            output_dir=os.path.join(args.workdir, "runs"))
     # single-task suite named "default": ledger rows, metric names and the
     # control plane's "MRR@10" spec are exactly the legacy pipeline's.
     suite = ValidationSuite(spec, [
@@ -232,10 +238,10 @@ def run(args) -> dict:
     return results
 
 
-def main():
-    ap = argparse.ArgumentParser()
+def parse_args(argv=None) -> argparse.Namespace:
+    ap = argparse.ArgumentParser(prog="python -m repro.launch.train")
     ap.add_argument("--arch", default="dr-bert-base")
-    ap.add_argument("--workdir", default="/tmp/asyncval_train")
+    ap.add_argument("--workdir", default="asyncval_train")
     ap.add_argument("--steps", type=int, default=60)
     ap.add_argument("--ckpt-every", type=int, default=10)
     ap.add_argument("--batch-size", type=int, default=16)
@@ -249,6 +255,9 @@ def main():
     ap.add_argument("--subset", action="store_true")
     ap.add_argument("--sync", action="store_true")
     ap.add_argument("--full", action="store_true")
+    ap.add_argument("--write-run", action="store_true",
+                    help="write each validated checkpoint's TREC run to "
+                         "<workdir>/runs")
     # convergence control plane (repro.control)
     ap.add_argument("--early-stop-patience", type=int, default=0,
                     help="evaluations without improvement before the "
@@ -281,9 +290,15 @@ def main():
                     help="spill directory (e.g. under /dev/shm) mirroring "
                          "the ring for cross-process fleet workers; empty "
                          "= in-process hand-off only")
-    args = ap.parse_args()
-    run(args)
+    return ap.parse_args(argv)
+
+
+def main(argv=None) -> int:
+    results = run(parse_args(argv))
+    return 1 if results["errors"] else 0
 
 
 if __name__ == "__main__":
-    main()
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
+    sys.exit(main())

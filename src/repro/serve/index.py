@@ -36,7 +36,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.engine import (TokenStore, chunk_geometry, doc_cache_dir,
-                               encode_store)
+                               encode_store, place_params)
 from repro.core.precision import validate_score_dtype
 from repro.core.retrieval import topk_exact, topk_sharded
 
@@ -166,6 +166,9 @@ class IndexBuilder:
         t0 = time.time()
         axis_names = (tuple(cfg.mesh.axis_names)
                       if cfg.mesh is not None else None)
+        # device-resident like the index: every query micro-batch encodes
+        # with these params
+        params = place_params(params, cfg.mesh)
         c_emb = encode_store(self.spec.encode_passage, params, self.store,
                              mesh=cfg.mesh, axis_names=axis_names)
         n_docs = int(c_emb.shape[0])

@@ -175,6 +175,7 @@ class Trainer:
 
     def run(self, on_metrics: Optional[Callable[[int, dict], None]] = None):
         history = []
+        prev = None
         while self.step < self.cfg.total_steps:
             if self._stop_requested():
                 self.stopped_early = True
@@ -182,6 +183,11 @@ class Trainer:
             batch = self.batch_iter(self.step)
             self.params, self.opt_state, metrics = self._step_fn(
                 self.params, self.opt_state, batch)
+            # at most one step queued behind the running one: every queued
+            # step holds its own copy of the params and optimizer state
+            if prev is not None:
+                jax.block_until_ready(prev)
+            prev = metrics
             self.step += 1
             # log/notify BEFORE committing the checkpoint: consumers of the
             # metrics feed (the control plane's train-loss lookup) are then

@@ -11,7 +11,6 @@ import numpy as np
 import pytest
 from jax.sharding import PartitionSpec as P
 
-from repro.distributed import compat
 from repro.distributed import fault
 from repro.distributed import sharding as shd
 
@@ -74,7 +73,8 @@ def test_spec_for_joint_axes():
 
 def test_opt_state_shardings_adam_and_adafactor():
     from repro.train import optim
-    mesh = compat.make_mesh((1, 1), ("data", "model"))
+    mesh = jax.make_mesh((1, 1), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
     params_abs = {"w": jax.ShapeDtypeStruct((64, 32), jnp.float32),
                   "b": jax.ShapeDtypeStruct((32,), jnp.float32)}
     param_sh = {"w": jax.NamedSharding(mesh, P("data", "model")),
@@ -196,9 +196,9 @@ def test_sharded_rerank_multidevice_subprocess():
         import jax, jax.numpy as jnp, numpy as np
         from repro.core import engine as E
         from repro.core import retrieval as R
-        from repro.distributed import compat
 
-        mesh = compat.make_mesh((4, 2), ("data", "model"))
+        mesh = jax.make_mesh((4, 2), ("data", "model"),
+                             axis_types=(jax.sharding.AxisType.Auto,) * 2)
         rng = np.random.default_rng(0)
         N, Q, D, chunk, k = 96, 6, 16, 24, 50     # chunk % 8 shards == 0
         # integer-valued table/queries: exact float32 dot products, so the

@@ -314,9 +314,9 @@ def test_stream_sharded_multidevice_subprocess():
         import jax, jax.numpy as jnp, numpy as np
         from repro.core import engine as E
         from repro.core import retrieval as R
-        from repro.distributed import compat
 
-        mesh = compat.make_mesh((4, 2), ("data", "model"))
+        mesh = jax.make_mesh((4, 2), ("data", "model"),
+                             axis_types=(jax.sharding.AxisType.Auto,) * 2)
         rng = np.random.default_rng(0)
         N, Q, D, k, chunk = 100, 5, 16, 17, 24
         params = {"table": jnp.asarray(rng.normal(size=(64, D)), jnp.float32)}
@@ -388,3 +388,42 @@ def test_validator_engine_injection(tmp_path, ds, baseline_run):
         sampler=RunFileTopK(depth=5),
         baseline_run=baseline_run).validate_params(params, step=1)
     assert v.results[0].metrics == stream_res.metrics
+
+
+@pytest.mark.parametrize("window", [1, 4])
+def test_engine_places_host_params_once(window):
+    """A restored checkpoint arrives as host arrays.  The engine puts it
+    on the device once per run, so every jitted dispatch gets device
+    arrays; a host array would be copied to the device again on each
+    dispatch, and the queued dispatches would hold every copy at once."""
+    from repro.serve import IndexBuilder, ServeConfig
+    rng = np.random.default_rng(0)
+    host = {"table": rng.normal(size=(VOCAB, DIM)).astype(np.float32)}
+    spec = EncoderSpec(name="gather", dim=DIM, encode_query=_gather_encode,
+                       encode_passage=_gather_encode, init=None,
+                       q_max_len=2, p_max_len=2)
+    docs = [[i % VOCAB] for i in range(40)]
+    queries = [[i] for i in range(5)]
+    stage = E.StreamTopKStage(_gather_encode, k=5,
+                              query_ids=[f"q{i}" for i in range(5)],
+                              doc_ids=[f"d{i}" for i in range(40)],
+                              window=window)
+    seen = []
+
+    def spy(fn):
+        def call(params, *args):
+            seen.append(all(isinstance(x, jax.Array)
+                            for x in jax.tree_util.tree_leaves(params)))
+            return fn(params, *args)
+        return call
+
+    stage.step = spy(stage.step)
+    stage.step_window = spy(stage.step_window)
+    eng = E.StreamingEngine(
+        spec, E.TokenStore.build(docs, max_len=2, chunk=8),
+        E.TokenStore.build(queries, max_len=2, chunk=8), stage)
+    eng.run(host)
+    assert seen and all(seen)
+    index = IndexBuilder(spec, {f"d{i}": t for i, t in enumerate(docs)},
+                         ServeConfig(k=5, batch_size=8)).build(host, 1)
+    assert isinstance(index.params["table"], jax.Array)

@@ -331,10 +331,10 @@ def test_sharded_query_encoding_subprocess():
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
         import jax, jax.numpy as jnp, numpy as np
         from repro.core import engine as E
-        from repro.distributed import compat
         from repro.distributed.sharding import rows_sharding
 
-        mesh = compat.make_mesh((4, 2), ("data", "model"))
+        mesh = jax.make_mesh((4, 2), ("data", "model"),
+                             axis_types=(jax.sharding.AxisType.Auto,) * 2)
         rng = np.random.default_rng(0)
         D = 16
         params = {"table": jnp.asarray(rng.normal(size=(64, D)), jnp.float32)}

@@ -855,3 +855,29 @@ def test_async_validator_extra_protect_and_gc_end_to_end(tmp_path):
     # the survivor still answers queries from the protected checkpoint
     qid = next(iter(ds.queries))
     assert service.answer([(qid, ds.queries[qid])])[0].step == steps[0]
+
+
+def test_pool_starts_one_worker_per_tpu_host(monkeypatch):
+    """libtpu lets one process hold a host's chips: the pool starts one
+    worker there and refuses a second, before spawning anything."""
+    from repro.launch import fleet
+    monkeypatch.setattr(fleet, "host_tpu_chips", lambda: 4)
+    pool = fleet.LocalWorkerPool()
+    with pytest.raises(RuntimeError, match="one process holds"):
+        pool.spawn([sys.executable, "-c", "pass"], 2)
+    assert pool.workers == []
+    pool.spawn([sys.executable, "-c", "pass"], 1)
+    try:
+        with pytest.raises(RuntimeError):
+            pool.spawn([sys.executable, "-c", "pass"], 1)
+    finally:
+        pool.shutdown()
+    assert len(pool.workers) == 1
+    with pytest.raises(SystemExit):
+        fleet.main(["--workers", "2", "--", sys.executable, "-c", "pass"])
+
+
+def test_host_tpu_chips_skips_workers_pinned_off_tpu(monkeypatch):
+    from repro.launch import fleet
+    monkeypatch.setenv("JAX_PLATFORMS", "cpu")
+    assert fleet.host_tpu_chips() == 0

@@ -75,9 +75,9 @@ def test_dryrun_machinery_small_mesh():
         import numpy as np
         from repro.launch import analysis
         from repro.launch.steps import build_step
-        from repro.distributed import compat
 
-        mesh = compat.make_mesh((4, 2), ("data", "model"))
+        mesh = jax.make_mesh((4, 2), ("data", "model"),
+                             axis_types=(jax.sharding.AxisType.Auto,) * 2)
         spec = build_step("dr-bert-base", "encode_corpus", mesh,
                           variant="cost")
         jitted = jax.jit(spec.fn, in_shardings=spec.in_shardings,

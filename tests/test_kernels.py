@@ -122,6 +122,24 @@ def test_topk_mips_n_valid_mask_narrow_dtypes(score_dtype):
     np.testing.assert_array_equal(np.asarray(i1), np.asarray(i2))
 
 
+def test_topk_mips_merge_ties_match_lax_top_k():
+    """The in-kernel merge (k rounds of max-extraction) keeps lax.top_k's
+    order on exact ties, across corpus tiles and the n_valid mask:
+    integer-valued inputs make every dot product exact, and duplicated
+    corpus rows make ties that span tiles."""
+    rng = np.random.default_rng(3)
+    q = jnp.asarray(rng.integers(-2, 3, size=(8, 128)), jnp.float32)
+    rows = rng.integers(-2, 3, size=(64, 128))
+    c = jnp.asarray(np.concatenate([rows, rows, rows[:40]]), jnp.float32)
+    k, n_valid = 24, 150
+    s, i = topk_mips(q, c, k=k, bn=128, n_valid=n_valid, interpret=True)
+    full = np.asarray(q) @ np.asarray(c).T
+    full[:, n_valid:] = -np.inf
+    es, ei = jax.lax.top_k(jnp.asarray(full), k)
+    np.testing.assert_array_equal(np.asarray(s), np.asarray(es))
+    np.testing.assert_array_equal(np.asarray(i), np.asarray(ei))
+
+
 def test_topk_mips_kernel_rejects_k_gt_bn():
     """The raw kernels assert k <= bn (a top-k wider than a corpus tile has
     no single-tile merge); the ops wrapper instead GROWS bn and succeeds."""

@@ -37,7 +37,6 @@ from repro.core import engine as E
 from repro.core import retrieval as R
 from repro.core.pipeline import ValidationConfig, ValidationPipeline
 from repro.core.samplers import SubsetResult
-from repro.distributed import compat
 from repro.models.biencoder import EncoderSpec
 from tests.hypothesis_compat import HAVE_HYPOTHESIS, given, settings, st
 
@@ -69,7 +68,8 @@ def mesh1():
     (sharded specs, axis_index, hierarchical slot merge) deterministically;
     true multi-device behaviour is covered by the subprocess test in
     tests/test_distributed.py."""
-    return compat.make_mesh((1,), ("data",))
+    return jax.make_mesh((1,), ("data",),
+                         axis_types=(jax.sharding.AxisType.Auto,))
 
 
 def _drive_stage(stage, store, params, q_emb):
@@ -212,7 +212,8 @@ def test_parity_seeded_fuzz(mesh1):
 def test_parity_property(seed):
     """Hypothesis-driven exploration of the same invariant (skipped when
     hypothesis is absent, see tests/hypothesis_compat.py)."""
-    mesh = compat.make_mesh((1,), ("data",))
+    mesh = jax.make_mesh((1,), ("data",),
+                         axis_types=(jax.sharding.AxisType.Auto,))
     rng = np.random.default_rng(seed)
     n_docs, cand_lists, k, chunk = _random_scenario(rng)
     _check_parity(mesh, n_docs, cand_lists, k=k, chunk=chunk,

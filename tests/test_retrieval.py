@@ -76,8 +76,8 @@ def test_topk_sharded_multidevice_subprocess():
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
         import jax, jax.numpy as jnp, numpy as np
         from repro.core import retrieval as R
-        from repro.distributed import compat
-        mesh = compat.make_mesh((4, 2), ("data", "model"))
+        mesh = jax.make_mesh((4, 2), ("data", "model"),
+                             axis_types=(jax.sharding.AxisType.Auto,) * 2)
         rng = np.random.default_rng(0)
         q = jnp.asarray(rng.normal(size=(6, 16)), jnp.float32)
         c = jnp.asarray(rng.normal(size=(400, 16)), jnp.float32)

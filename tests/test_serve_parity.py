@@ -10,6 +10,7 @@ fixed-shape padding and arbitrary batch boundaries.
 
 import concurrent.futures
 
+import jax
 import numpy as np
 import pytest
 
@@ -20,7 +21,6 @@ from repro.core import metrics as metrics_lib
 from repro.core.suite import (ValidationConfig, ValidationSuite,
                               ValidationTask)
 from repro.data import corpus as corpus_lib
-from repro.distributed import compat
 from repro.serve import IndexBuilder, QueryService, ServeConfig
 
 K = 10
@@ -79,7 +79,10 @@ def test_serve_matches_validator_bitwise(setup, score_dtype, sharded):
     1-device mesh — the full shard_map/hierarchical-merge machinery runs
     deterministically (multi-device is the slow-tier subprocess test)."""
     ds, spec, params = setup
-    mesh = compat.make_mesh((1,), ("data",)) if sharded else None
+    mesh = None
+    if sharded:
+        mesh = jax.make_mesh((1,), ("data",),
+                             axis_types=(jax.sharding.AxisType.Auto,))
     suite = _suite(ds, spec, score_dtype=score_dtype, mesh=mesh)
     val_run, val_scores, _ = suite.engine("default").run(params)
     srv_run, srv_scores = _serve_run(ds, spec, params,
@@ -190,10 +193,10 @@ def test_serve_parity_multidevice_padded():
         import numpy as np
         from benchmarks.common import toy_spec, train_toy_dr
         from repro.data import corpus as corpus_lib
-        from repro.distributed import compat
         from repro.serve import IndexBuilder, ServeConfig
         from repro.core.encoder import jitted_encoder
         from repro.data.corpus import pad_batch
+        import jax
         import jax.numpy as jnp
 
         ds = corpus_lib.synthetic_retrieval_dataset(3, n_passages=205,
@@ -201,7 +204,8 @@ def test_serve_parity_multidevice_padded():
         spec = toy_spec(ds.vocab)
         _, snaps = train_toy_dr(ds, spec, steps=10, snapshot_every=10)
         params = snaps[-1][1]
-        mesh = compat.make_mesh((8,), ("data",))
+        mesh = jax.make_mesh((8,), ("data",),
+                             axis_types=(jax.sharding.AxisType.Auto,))
         assert 205 % 8 != 0
         qids = list(ds.queries)
         toks, mask = pad_batch([ds.queries[q] for q in qids],

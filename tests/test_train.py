@@ -108,6 +108,34 @@ def test_trainer_runs_and_checkpoints(tmp_path):
     assert hist[-1][1]["loss"] < hist[0][1]["loss"]
 
 
+def test_trainer_keeps_one_step_queued():
+    """Each queued step holds its own params and optimizer state, so the
+    trainer lets at most one step wait behind the running one: when step
+    n is dispatched, step n-2 has finished."""
+    def slow_loss(params, batch):
+        # device work per step far longer than a dispatch
+        m = jnp.eye(256) * 0.5
+        m = jax.lax.fori_loop(0, 40, lambda _, a: a @ m + m, m)
+        loss, aux = loss_fn(params, batch)
+        return loss + 0.0 * m.sum(), aux
+
+    cfg = TrainerConfig(total_steps=12, log_every=100)
+    tr = Trainer(cfg, slow_loss, optim.adamw(5e-2), init_params(), batch_for)
+    outs, ready = [], []
+    step_fn = tr._step_fn
+
+    def spy(*args):
+        if len(outs) >= 2:
+            ready.append(all(x.is_ready()
+                             for x in jax.tree_util.tree_leaves(outs[-2])))
+        outs.append(step_fn(*args))
+        return outs[-1]
+
+    tr._step_fn = spy
+    tr.run()
+    assert len(ready) == 10 and all(ready)
+
+
 def test_trainer_restart_resumes_exactly(tmp_path):
     """Kill-and-restart must produce bit-identical params to an
     uninterrupted run (params + opt state + data cursor restored)."""
@@ -146,3 +174,20 @@ def test_grad_accum_matches_large_batch():
     np.testing.assert_allclose(np.asarray(p1["w"]), np.asarray(p4["w"]),
                                rtol=1e-5)
     assert float(m1["loss"]) == pytest.approx(float(m4["loss"]), rel=1e-5)
+
+
+@pytest.mark.parametrize("fail", [False, True],
+                         ids=["clean", "failing_validation"])
+def test_train_main_exit_code(tmp_path, monkeypatch, fail):
+    """``python -m repro.launch.train`` exits 1 when any checkpoint failed
+    to validate, 0 when every one validated."""
+    from repro.core.suite import ValidationSuite
+    from repro.launch import train
+    if fail:
+        def broken(self, *args, **kwargs):
+            raise RuntimeError("injected validation failure")
+        monkeypatch.setattr(ValidationSuite, "run_unit", broken)
+    rc = train.main(["--workdir", str(tmp_path), "--steps", "2",
+                     "--ckpt-every", "2", "--corpus-size", "40",
+                     "--n-queries", "6", "--batch-size", "4"])
+    assert rc == (1 if fail else 0)
