@@ -45,6 +45,7 @@ behind the same interface for A/B benchmarking
 from __future__ import annotations
 
 import collections
+import contextlib
 import dataclasses
 import hashlib
 import json
@@ -77,6 +78,23 @@ def _donate(*argnums: int) -> tuple:
     """Donation positions for the top-k carry — skipped on CPU where XLA
     cannot alias the buffers (it would only warn)."""
     return () if jax.default_backend() == "cpu" else argnums
+
+
+_NULL_CM = contextlib.nullcontext()
+
+
+def _span(tel, name: str, **attrs):
+    """``tel.span(name)``, or a shared no-op without telemetry."""
+    return _NULL_CM if tel is None else tel.span(name, **attrs)
+
+
+def _in_scope(scope: str, fn: Callable) -> Callable:
+    """``fn`` traced under ``jax.named_scope(scope)``, so a jit of it names
+    its device ops by ``scope``."""
+    def scoped(*args):
+        with jax.named_scope(scope):
+            return fn(*args)
+    return scoped
 
 
 # ---------------------------------------------------------------------------
@@ -522,10 +540,22 @@ class Stage:
     """One streaming validation strategy: a device carry folded chunk by chunk.
 
     ``init(q_emb) -> carry``; ``step(params, q_emb, carry, toks, mask, base,
-    n_valid) -> carry``; ``finalize(carry) -> (run, run_scores)``.
+    n_valid) -> carry``; ``finalize(carry) -> (run, run_scores)``, where
+    the engine hands ``finalize`` the carry already copied to the host.
+
+    Each jitted program a step dispatches goes through :meth:`_call`: with a
+    ``telemetry`` attached (the engine attaches its own), the program's
+    device scopes are registered on it once per argument shapes.
     """
 
     name = "stage"
+    telemetry = None
+
+    def _call(self, fn: Callable, *args):
+        tel = self.telemetry
+        if tel is not None:
+            tel.register_program(fn, args)
+        return fn(*args)
 
     def init(self, q_emb: jnp.ndarray):
         raise NotImplementedError
@@ -562,24 +592,27 @@ class StreamTopKStage(Stage):
 
         def fold(carry, q_emb, params, toks, mask, base, n_valid):
             run_s, run_i = carry
-            emb = encode_fn(params, toks, mask)               # (chunk, D)
-            # static precision branch: "f32" keeps the literal legacy
-            # expression (bit-for-bit); narrow dtypes cast the chunk's
-            # embeddings once, right here, and dequantize to f32 scores
-            # before the mask + merge below ever see them.
-            if score_dtype == "f32":
-                s = (q_emb @ emb.T).astype(jnp.float32)       # (Q, chunk)
-            else:
-                s = chunk_scores(q_emb, emb, score_dtype)     # (Q, chunk)
+            with jax.named_scope("encode"):
+                emb = encode_fn(params, toks, mask)           # (chunk, D)
             chunk = toks.shape[0]
             col = jnp.arange(chunk, dtype=jnp.int32)
-            s = jnp.where((col < n_valid)[None, :], s, -jnp.inf)
+            with jax.named_scope("score"):
+                # static precision branch: "f32" keeps the literal legacy
+                # expression (bit-for-bit); narrow dtypes cast the chunk's
+                # embeddings once, right here, and dequantize to f32 scores
+                # before the mask + merge below ever see them.
+                if score_dtype == "f32":
+                    s = (q_emb @ emb.T).astype(jnp.float32)   # (Q, chunk)
+                else:
+                    s = chunk_scores(q_emb, emb, score_dtype)  # (Q, chunk)
+                s = jnp.where((col < n_valid)[None, :], s, -jnp.inf)
             # single top_k over [carry ‖ chunk]: selecting top-k of the union
             # directly is identical to local-top-k-then-merge (top-k of a set
             # equals top-k of carry ∪ top-k(chunk)) but does one sort of
             # width k+chunk instead of two of width chunk and 2k.
-            gcol = jnp.broadcast_to((col + base)[None, :], s.shape)
-            return _merge_topk(run_s, run_i, s, gcol, k_carry)
+            with jax.named_scope("merge"):
+                gcol = jnp.broadcast_to((col + base)[None, :], s.shape)
+                return _merge_topk(run_s, run_i, s, gcol, k_carry)
 
         def fused(params, q_emb, run_s, run_i, toks, mask, base, n_valid):
             return fold((run_s, run_i), q_emb, params, toks, mask, base,
@@ -606,17 +639,17 @@ class StreamTopKStage(Stage):
 
     def step(self, params, q_emb, carry, toks, mask, base, n_valid):
         run_s, run_i = carry
-        return self._fused(params, q_emb, run_s, run_i, toks, mask,
-                           jnp.asarray(base, jnp.int32),
-                           jnp.asarray(n_valid, jnp.int32))
+        return self._call(self._fused, params, q_emb, run_s, run_i, toks,
+                          mask, jnp.asarray(base, jnp.int32),
+                          jnp.asarray(n_valid, jnp.int32))
 
     def step_window(self, params, q_emb, carry, toks_w, mask_w, bases,
                     n_valids):
         """Fold ``window`` chunks in one dispatch (scan inside the jit)."""
         run_s, run_i = carry
-        return self._fused_window(params, q_emb, run_s, run_i, toks_w,
-                                  mask_w, jnp.asarray(bases, jnp.int32),
-                                  jnp.asarray(n_valids, jnp.int32))
+        return self._call(self._fused_window, params, q_emb, run_s, run_i,
+                          toks_w, mask_w, jnp.asarray(bases, jnp.int32),
+                          jnp.asarray(n_valids, jnp.int32))
 
     def finalize(self, carry):
         run_s, run_i = np.asarray(carry[0]), np.asarray(carry[1])
@@ -640,11 +673,11 @@ class PallasStreamTopKStage(StreamTopKStage):
         # XLA scan fallback.
         super().__init__(encode_fn, k=k, query_ids=query_ids, doc_ids=doc_ids,
                          window=1, score_dtype=score_dtype)
-        self._encode = jitted_encoder(encode_fn)
+        self._encode = jax.jit(_in_scope("encode", encode_fn))
 
     def step(self, params, q_emb, carry, toks, mask, base, n_valid):
         from repro.kernels.topk_mips import ops as mips_ops
-        emb = self._encode(params, toks, mask)                # device-resident
+        emb = self._call(self._encode, params, toks, mask)    # device-resident
         run_s, run_i = carry
         return mips_ops.topk_mips_chunk(q_emb, emb, run_s, run_i, base=base,
                                         n_valid=n_valid,
@@ -673,22 +706,27 @@ class ShardedStreamTopKStage(StreamTopKStage):
         ax = axis_names[0] if len(axis_names) == 1 else axis_names
 
         def local(params, q_emb, run_s, run_i, toks, mask, base, n_valid):
-            emb = encode_fn(params, toks, mask)               # (rows, D) local
+            with jax.named_scope("encode"):
+                emb = encode_fn(params, toks, mask)           # (rows, D) local
             rows = toks.shape[0]
             shard = jax.lax.axis_index(ax)
-            # per-ROW quantization is sharding-independent, so each shard's
-            # local quantized scores equal the single-device stage's slice
-            if score_dtype == "f32":
-                s = (q_emb @ emb.T).astype(jnp.float32)       # (Q, rows)
-            else:
-                s = chunk_scores(q_emb, emb, score_dtype)     # (Q, rows)
             col = shard * rows + jnp.arange(rows, dtype=jnp.int32)
-            s = jnp.where((col < n_valid)[None, :], s, -jnp.inf)
-            kk = min(k_carry, rows)
-            bs, pos = jax.lax.top_k(s, kk)
-            bi = jnp.take(col, pos) + base                    # global doc rows
-            bs, bi = _hierarchical_topk_merge(bs, bi, axis_names, k_carry)
-            return _merge_topk(run_s, run_i, bs, bi, k_carry)
+            with jax.named_scope("score"):
+                # per-ROW quantization is sharding-independent, so each
+                # shard's local quantized scores equal the single-device
+                # stage's slice
+                if score_dtype == "f32":
+                    s = (q_emb @ emb.T).astype(jnp.float32)   # (Q, rows)
+                else:
+                    s = chunk_scores(q_emb, emb, score_dtype)  # (Q, rows)
+                s = jnp.where((col < n_valid)[None, :], s, -jnp.inf)
+            with jax.named_scope("merge"):
+                kk = min(k_carry, rows)
+                bs, pos = jax.lax.top_k(s, kk)
+                bi = jnp.take(col, pos) + base                # global doc rows
+                bs, bi = _hierarchical_topk_merge(bs, bi, axis_names,
+                                                  k_carry)
+                return _merge_topk(run_s, run_i, bs, bi, k_carry)
 
         spec_rows = P(ax)
         # check_vma=False: the carry is replicated-in, device-varying
@@ -705,9 +743,9 @@ class ShardedStreamTopKStage(StreamTopKStage):
 
     def step(self, params, q_emb, carry, toks, mask, base, n_valid):
         run_s, run_i = carry
-        return self._fused(params, q_emb, run_s, run_i, toks, mask,
-                           jnp.asarray(base, jnp.int32),
-                           jnp.asarray(n_valid, jnp.int32))
+        return self._call(self._fused, params, q_emb, run_s, run_i, toks,
+                          mask, jnp.asarray(base, jnp.int32),
+                          jnp.asarray(n_valid, jnp.int32))
 
 
 class StreamRerankStage(Stage):
@@ -757,20 +795,25 @@ class StreamRerankStage(Stage):
 
         def fused(params, q_emb, cand_s, cand_idx, toks, mask, row_mask,
                   base, n_valid):
-            emb = encode_fn(params, toks, mask)               # (chunk, D)
-            if score_dtype == "f32":
-                s = (q_emb @ emb.T).astype(jnp.float32)       # (Q, chunk)
-            else:
-                s = chunk_scores(q_emb, emb, score_dtype)     # (Q, chunk)
+            with jax.named_scope("encode"):
+                emb = encode_fn(params, toks, mask)           # (chunk, D)
             chunk = toks.shape[0]
-            # score only candidate-member rows (membership precomputed per
-            # chunk on the TokenStore side); hit slots always reference
-            # member rows, so the gather below sees unmasked scores.
-            s = jnp.where(row_mask[None, :], s, -jnp.inf)
-            local = cand_idx - base
-            hit = (cand_idx >= 0) & (local >= 0) & (local < n_valid)
-            g = jnp.take_along_axis(s, jnp.clip(local, 0, chunk - 1), axis=1)
-            return jnp.where(hit, g, cand_s)
+            with jax.named_scope("score"):
+                if score_dtype == "f32":
+                    s = (q_emb @ emb.T).astype(jnp.float32)   # (Q, chunk)
+                else:
+                    s = chunk_scores(q_emb, emb, score_dtype)  # (Q, chunk)
+                # score only candidate-member rows (membership precomputed
+                # per chunk on the TokenStore side); hit slots always
+                # reference member rows, so the gather below sees unmasked
+                # scores.
+                s = jnp.where(row_mask[None, :], s, -jnp.inf)
+            with jax.named_scope("merge"):
+                local = cand_idx - base
+                hit = (cand_idx >= 0) & (local >= 0) & (local < n_valid)
+                g = jnp.take_along_axis(s, jnp.clip(local, 0, chunk - 1),
+                                        axis=1)
+                return jnp.where(hit, g, cand_s)
 
         self._fused = jax.jit(fused, donate_argnums=_donate(2,))
 
@@ -840,10 +883,10 @@ class StreamRerankStage(Stage):
     def step(self, params, q_emb, carry, toks, mask, base, n_valid):
         ci = base // (self.cmap.chunk if self.cmap is not None
                       else max(toks.shape[0], 1))
-        return self._fused(params, q_emb, carry, self.cand_idx, toks, mask,
-                           self._row_mask(ci, toks.shape[0]),
-                           jnp.asarray(base, jnp.int32),
-                           jnp.asarray(n_valid, jnp.int32))
+        return self._call(self._fused, params, q_emb, carry, self.cand_idx,
+                          toks, mask, self._row_mask(ci, toks.shape[0]),
+                          jnp.asarray(base, jnp.int32),
+                          jnp.asarray(n_valid, jnp.int32))
 
     def finalize(self, carry):
         return rank_candidates(self.query_ids, np.asarray(carry), self.cands,
@@ -885,26 +928,31 @@ class ShardedStreamRerankStage(StreamRerankStage):
 
         def local(params, q_emb, cand_s, cand_idx, toks, mask, row_mask,
                   base, n_valid):
-            emb = encode_fn(params, toks, mask)           # (rows, D) local
+            with jax.named_scope("encode"):
+                emb = encode_fn(params, toks, mask)       # (rows, D) local
             rows = toks.shape[0]
             shard = jax.lax.axis_index(ax)
-            # per-row quantization: shard-local quantized scores equal the
-            # single-device stage's slice (see ShardedStreamTopKStage)
-            if score_dtype == "f32":
-                s = (q_emb @ emb.T).astype(jnp.float32)   # (Q, rows) local
-            else:
-                s = chunk_scores(q_emb, emb, score_dtype)  # (Q, rows) local
             col = shard * rows + jnp.arange(rows, dtype=jnp.int32)
-            s = jnp.where((row_mask & (col < n_valid))[None, :], s, -jnp.inf)
-            pos = cand_idx - base - shard * rows          # shard-local slot
-            hit = (cand_idx >= 0) & (cand_idx - base < n_valid) \
-                & (pos >= 0) & (pos < rows)
-            g = jnp.take_along_axis(s, jnp.clip(pos, 0, rows - 1), axis=1)
-            part = jnp.where(hit, g, cand_s)
-            # slot-aligned hierarchical merge: each slot's row lives on one
-            # shard, so max(part over shards) == the written score where a
-            # shard hit and the (replicated) carry everywhere else.
-            return _hierarchical_slot_max(part, axis_names)
+            with jax.named_scope("score"):
+                # per-row quantization: shard-local quantized scores equal
+                # the single-device stage's slice (see ShardedStreamTopKStage)
+                if score_dtype == "f32":
+                    s = (q_emb @ emb.T).astype(jnp.float32)   # (Q, rows)
+                else:
+                    s = chunk_scores(q_emb, emb, score_dtype)  # (Q, rows)
+                s = jnp.where((row_mask & (col < n_valid))[None, :], s,
+                              -jnp.inf)
+            with jax.named_scope("merge"):
+                pos = cand_idx - base - shard * rows      # shard-local slot
+                hit = (cand_idx >= 0) & (cand_idx - base < n_valid) \
+                    & (pos >= 0) & (pos < rows)
+                g = jnp.take_along_axis(s, jnp.clip(pos, 0, rows - 1),
+                                        axis=1)
+                part = jnp.where(hit, g, cand_s)
+                # slot-aligned hierarchical merge: each slot's row lives on
+                # one shard, so max(part over shards) == the written score
+                # where a shard hit and the (replicated) carry elsewhere.
+                return _hierarchical_slot_max(part, axis_names)
 
         spec_rows = P(ax)
         # check_vma=False: the carry enters replicated, is device-varying
@@ -1075,10 +1123,10 @@ class StreamingEngine:
         self.staging_depth = staging_depth
         self.query_mesh = query_mesh
         self.query_axis_names = query_axis_names
-        # nullable repro.obs.Telemetry: staged/encoded spans + per-chunk
-        # step-time and staging idle-gap metrics.  Observation only — the
-        # schedule, staging, and scoring math are identical with or without
-        # it (the timed next() below is the same next() zip() would issue).
+        # nullable repro.obs.Telemetry: a span at each boundary of a run
+        # (placed, encoded, staged and dispatch per batch, drain, finalize)
+        # and the staging-wait histogram.  Observation only — the schedule,
+        # staging, and scoring math are identical with or without it.
         self.telemetry = telemetry
 
     @property
@@ -1092,16 +1140,15 @@ class StreamingEngine:
         # place the checkpoint once: host arrays (a restored checkpoint)
         # handed to every jitted dispatch are copied to the device per
         # dispatch, and queued dispatches keep all those copies alive
-        params = place_params(params, self.query_mesh)
+        with _span(tel, "placed"):
+            params = place_params(params, self.query_mesh)
         t0 = time.time()
-        m0 = time.monotonic() if tel is not None else 0.0
-        q_emb = encode_store(self.spec.encode_query, params, self.query_store,
-                             mesh=self.query_mesh,
-                             axis_names=self.query_axis_names)
-        q_emb.block_until_ready()
+        with _span(tel, "encoded", role="query"):
+            q_emb = encode_store(self.spec.encode_query, params,
+                                 self.query_store, mesh=self.query_mesh,
+                                 axis_names=self.query_axis_names)
+            q_emb.block_until_ready()
         t_query = time.time() - t0
-        if tel is not None:
-            tel.record("encoded", m0, t_query, role="query")
 
         t0 = time.time()
         # a compacting rerank stage re-packed the candidate rows into its
@@ -1126,58 +1173,56 @@ class StreamingEngine:
             store, schedule,
             depth=1 if self.staging == "sync" else self.staging_depth,
             sharding=getattr(self.stage, "input_sharding", None))
-        # explicit next() instead of zip() so telemetry can time the
-        # staging wait (prefetch idle gap) separately from the fused step
-        # dispatch; the iteration order and count are identical to the old
-        # zip(schedule, batches) loop.
-        m_stream = time.monotonic() if tel is not None else 0.0
+        self.stage.telemetry = tel
+        # explicit next() instead of zip() so telemetry can span the
+        # staging wait of each batch apart from its dispatch; the iteration
+        # order and count are identical to a zip(schedule, batches) loop.
         t_wait = 0.0
-        step_hist = tel.metrics.histogram("engine.chunk_step_s") \
-            if tel is not None else None
         for ci, w in schedule:
             if tel is None:
                 toks, mask = next(batches)
+                carry = self._step(params, q_emb, carry, toks, mask, store,
+                                   ci, w)
             else:
-                m0 = time.monotonic()
-                toks, mask = next(batches)
-                t_wait += time.monotonic() - m0
-                m1 = time.monotonic()
-            if w > 1:
-                bases = store.chunk * np.arange(ci, ci + w, dtype=np.int32)
-                n_valids = np.asarray([store.rows_valid(j) for j in
-                                       range(ci, ci + w)], np.int32)
-                carry = self.stage.step_window(params, q_emb, carry, toks,
-                                               mask, bases, n_valids)
-            else:
-                carry = self.stage.step(params, q_emb, carry, toks, mask,
-                                        store.chunk * ci,
-                                        store.rows_valid(ci))
-            if tel is not None:
-                step_hist.observe(time.monotonic() - m1)
-        jax.block_until_ready(carry)
+                with tel.span("staged") as staged:
+                    toks, mask = next(batches)
+                t_wait += staged.dur
+                with tel.span("dispatch"):
+                    carry = self._step(params, q_emb, carry, toks, mask,
+                                       store, ci, w)
+        with _span(tel, "drain"):
+            jax.block_until_ready(carry)
         t_stream = time.time() - t0
         if tel is not None:
-            stream_total = max(time.monotonic() - m_stream, 1e-12)
-            idle_ratio = t_wait / stream_total
-            # aggregate staging-wait span for the run (duration = summed
-            # next() waits, not a contiguous interval — see obs.trace docs)
-            tel.record("staged", m_stream, t_wait, n_batches=len(schedule),
-                       staging=self.staging, idle_ratio=idle_ratio)
             tel.metrics.histogram("engine.staging_wait_s").observe(t_wait)
-            tel.metrics.histogram("engine.staging_idle_ratio").observe(
-                idle_ratio)
 
         t0 = time.time()
-        run, scores = self.stage.finalize(carry)
+        with _span(tel, "finalize"):
+            with _span(tel, "finalize.fetch"):
+                carry = jax.device_get(carry)
+            with _span(tel, "finalize.build"):
+                run, scores = self.stage.finalize(carry)
         t_final = time.time() - t0
         # key names kept from the legacy path: the ledger/CSV schema is
         # stable across engines.  encode_corpus_s is the fused loop (encode
         # AND fold — they are one program now); retrieve_s is the host-side
-        # finalize only.
+        # finalize only: the carry's copy to the host and the run lists.
         timings = {"encode_corpus_s": t_stream, "encode_query_s": t_query,
                    "retrieve_s": t_final,
                    "total_s": t_query + t_stream + t_final}
         return run, scores, timings
+
+    def _step(self, params, q_emb, carry, toks, mask, store: TokenStore,
+              ci: int, w: int):
+        """Dispatch the fused step over ``w`` chunks from chunk ``ci``."""
+        if w > 1:
+            bases = store.chunk * np.arange(ci, ci + w, dtype=np.int32)
+            n_valids = np.asarray([store.rows_valid(j) for j in
+                                   range(ci, ci + w)], np.int32)
+            return self.stage.step_window(params, q_emb, carry, toks, mask,
+                                          bases, n_valids)
+        return self.stage.step(params, q_emb, carry, toks, mask,
+                               store.chunk * ci, store.rows_valid(ci))
 
 
 class MaterializedEngine:
@@ -1213,28 +1258,27 @@ class MaterializedEngine:
     def run(self, params) -> Tuple[Run, Scores, Dict[str, float]]:
         tel = self.telemetry
         t0 = time.time()
-        m0 = time.monotonic() if tel is not None else 0.0
-        c_emb, _ = encode_texts(self.spec.encode_passage, params,
-                                self.doc_texts, max_len=self.spec.p_max_len,
-                                batch_size=self.batch_size)
-        if self.score_dtype == "bf16":
-            # the resident (N, D) matrix — THE memory cost this engine pays
-            # that streaming doesn't — shrinks 2x; scoring casts back per
-            # block with f32 accumulation.  int8 keeps the f32 matrix and
-            # quantizes at score time (value-level parity with streaming
-            # beats resident shrink for the A/B baseline engine).
-            c_emb = np.asarray(jnp.asarray(c_emb, jnp.bfloat16))
+        with _span(tel, "encoded", role="corpus"):
+            c_emb, _ = encode_texts(self.spec.encode_passage, params,
+                                    self.doc_texts,
+                                    max_len=self.spec.p_max_len,
+                                    batch_size=self.batch_size)
+            if self.score_dtype == "bf16":
+                # the resident (N, D) matrix — THE memory cost this engine
+                # pays that streaming doesn't — shrinks 2x; scoring casts
+                # back per block with f32 accumulation.  int8 keeps the f32
+                # matrix and quantizes at score time (value-level parity
+                # with streaming beats resident shrink for the A/B baseline
+                # engine).
+                c_emb = np.asarray(jnp.asarray(c_emb, jnp.bfloat16))
         t_corpus = time.time() - t0
-        if tel is not None:
-            tel.record("encoded", m0, t_corpus, role="corpus")
         t0 = time.time()
-        m0 = time.monotonic() if tel is not None else 0.0
-        q_emb, _ = encode_texts(self.spec.encode_query, params,
-                                self.query_texts, max_len=self.spec.q_max_len,
-                                batch_size=self.batch_size)
+        with _span(tel, "encoded", role="query"):
+            q_emb, _ = encode_texts(self.spec.encode_query, params,
+                                    self.query_texts,
+                                    max_len=self.spec.q_max_len,
+                                    batch_size=self.batch_size)
         t_query = time.time() - t0
-        if tel is not None:
-            tel.record("encoded", m0, t_query, role="query")
 
         t0 = time.time()
         if self.mode in ("rerank", "average_rank") and self.per_query:
@@ -1317,17 +1361,13 @@ def make_streaming_engine(spec, store: ValidationStore, vcfg):
     if doc_store is None:
         if vcfg.token_backing == "mmap" and not vcfg.mmap_dir:
             raise ValueError("token_backing='mmap' needs mmap_dir")
-        if tel is not None:
-            t0 = time.monotonic()
-        doc_store = TokenStore.build(
-            store.doc_texts, max_len=spec.p_max_len, chunk=chunk,
-            backing=vcfg.token_backing,
-            cache_dir=doc_cache_dir(vcfg.mmap_dir),
-            fingerprint=vcfg.token_fingerprint)
-        if tel is not None:
-            tel.record("store_build", t0, time.monotonic() - t0,
-                       n_docs=len(store.doc_texts),
-                       backing=vcfg.token_backing)
+        with _span(tel, "store_build", n_docs=len(store.doc_texts),
+                   backing=vcfg.token_backing):
+            doc_store = TokenStore.build(
+                store.doc_texts, max_len=spec.p_max_len, chunk=chunk,
+                backing=vcfg.token_backing,
+                cache_dir=doc_cache_dir(vcfg.mmap_dir),
+                fingerprint=vcfg.token_fingerprint)
     query_store = store.query_store
     if query_store is None:
         query_store = TokenStore.build(store.query_texts,

@@ -29,11 +29,13 @@ from repro.core.precision import chunk_scores, validate_score_dtype
 
 
 def _merge_topk(scores_a, idx_a, scores_b, idx_b, k: int):
-    """Merge two (Q, ka/kb) candidate sets into (Q, k)."""
-    s = jnp.concatenate([scores_a, scores_b], axis=1)
-    i = jnp.concatenate([idx_a, idx_b], axis=1)
-    top_s, pos = jax.lax.top_k(s, k)
-    return top_s, jnp.take_along_axis(i, pos, axis=1)
+    """Merge two (Q, ka/kb) candidate sets into (Q, k), under the
+    ``merge`` scope that device traces attribute the fold by."""
+    with jax.named_scope("merge"):
+        s = jnp.concatenate([scores_a, scores_b], axis=1)
+        i = jnp.concatenate([idx_a, idx_b], axis=1)
+        top_s, pos = jax.lax.top_k(s, k)
+        return top_s, jnp.take_along_axis(i, pos, axis=1)
 
 
 @functools.partial(jax.jit, static_argnames=("k", "block", "unroll",

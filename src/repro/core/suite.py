@@ -423,7 +423,11 @@ class ValidationSuite:
         names = list(task.metrics)
         if task.mode == "average_rank" and "AverageRank" not in names:
             names.append("AverageRank")
-        m = metrics_lib.compute_metrics(run, task.qrels, names)
+        if tel is None:
+            m = metrics_lib.compute_metrics(run, task.qrels, names)
+        else:
+            with tel.span("metrics", step=step, task=name):
+                m = metrics_lib.compute_metrics(run, task.qrels, names)
         v = self.vcfg
         do_write = v.write_run if write_runs is None else write_runs
         if do_write and v.output_dir:

@@ -297,6 +297,11 @@ class ValidatorWorker:
         self.heartbeat_interval_s = heartbeat_interval_s
         expected = tuple(getattr(pipeline, "task_names", ())
                          or ("default",))
+        if telemetry is None:
+            # one handle per validator: a worker given none observes with
+            # the suite's (the engine's spans already go there)
+            telemetry = getattr(getattr(pipeline, "vcfg", None),
+                                "telemetry", None)
         self.ledger = ledger if ledger is not None \
             else ValidationLedger(None, expected_tasks=expected,
                                   telemetry=telemetry)
@@ -317,23 +322,31 @@ class ValidatorWorker:
     # -- shared execution body ---------------------------------------------
     def load_params(self, step: int):
         if self._params_step != step:
-            snap = self.snapshots.get(step) \
-                if self.snapshots is not None else None
-            if snap is not None:
-                # pre-durable hand-off: reconstruct the exact state tree the
-                # durable restore would produce (same treedef, same leaf
-                # bytes, same shardings placement) — bit-parity is the
-                # contract, provenance is the only observable difference
-                self._params = self.params_extractor(
-                    snap.state(shardings=self.shardings))
-                self._params_handoff = "snapshot"
+            tel = self.telemetry
+            if tel is None:
+                self._load(step)
             else:
-                state, _ = ckpt.restore(self.ckpt_root, step,
-                                        shardings=self.shardings)
-                self._params = self.params_extractor(state)
-                self._params_handoff = ""
-            self._params_step = step
+                with tel.span("loaded", step=step):
+                    self._load(step)
         return self._params
+
+    def _load(self, step: int) -> None:
+        snap = self.snapshots.get(step) \
+            if self.snapshots is not None else None
+        if snap is not None:
+            # pre-durable hand-off: reconstruct the exact state tree the
+            # durable restore would produce (same treedef, same leaf bytes,
+            # same shardings placement) — bit-parity is the contract,
+            # provenance is the only observable difference
+            self._params = self.params_extractor(
+                snap.state(shardings=self.shardings))
+            self._params_handoff = "snapshot"
+        else:
+            state, _ = ckpt.restore(self.ckpt_root, step,
+                                    shardings=self.shardings)
+            self._params = self.params_extractor(state)
+            self._params_handoff = ""
+        self._params_step = step
 
     @property
     def last_handoff(self) -> str:

@@ -94,8 +94,10 @@ def _topk_mips_jit(q, c, *, k, bq, bn, interpret, n_valid):
     Np = _pad_to(N, bn)
     qp = jnp.pad(q, ((0, Qp - Q), (0, Dp - D)))
     cp = jnp.pad(c, ((0, Np - N), (0, Dp - D)))
-    scores, idx = topk_mips_kernel(qp, cp, k=kp, n_valid=n_valid, bq=bq,
-                                   bn=bn, interpret=interpret)
+    # the streaming stage's chunk fold: scores and local top-k in one kernel
+    with jax.named_scope("merge"):
+        scores, idx = topk_mips_kernel(qp, cp, k=kp, n_valid=n_valid, bq=bq,
+                                       bn=bn, interpret=interpret)
     return scores[:Q], idx[:Q]
 
 
@@ -128,9 +130,10 @@ def _topk_mips_int8_jit(q, c, *, k, bq, bn, interpret, n_valid):
     cp = jnp.pad(cv, ((0, Np - N), (0, Dp - D)))
     qsp = jnp.pad(qs, ((0, Qp - Q), (0, 0)), constant_values=1.0)
     csp = jnp.pad(cs, ((0, Np - N), (0, 0)), constant_values=1.0)
-    scores, idx = topk_mips_kernel_int8(qp, cp, qsp, csp.reshape(1, Np),
-                                        k=kp, n_valid=n_valid, bq=bq, bn=bn,
-                                        interpret=interpret)
+    with jax.named_scope("merge"):
+        scores, idx = topk_mips_kernel_int8(qp, cp, qsp, csp.reshape(1, Np),
+                                            k=kp, n_valid=n_valid, bq=bq,
+                                            bn=bn, interpret=interpret)
     return scores[:Q], idx[:Q]
 
 
@@ -139,10 +142,11 @@ def _merge_carry(run_s, run_i, chunk_s, chunk_i, base, *, k: int):
     """Fold a chunk-local top-k (indices relative to the chunk) into the
     running (Q, k) carry.  ``base`` is dynamic — one compile per chunk shape,
     not per chunk position."""
-    s = jnp.concatenate([run_s, chunk_s], axis=1)
-    i = jnp.concatenate([run_i, chunk_i + base], axis=1)
-    top_s, pos = jax.lax.top_k(s, k)
-    return top_s, jnp.take_along_axis(i, pos, axis=1)
+    with jax.named_scope("merge"):
+        s = jnp.concatenate([run_s, chunk_s], axis=1)
+        i = jnp.concatenate([run_i, chunk_i + base], axis=1)
+        top_s, pos = jax.lax.top_k(s, k)
+        return top_s, jnp.take_along_axis(i, pos, axis=1)
 
 
 def topk_mips_chunk(q: jnp.ndarray, c_chunk: jnp.ndarray, run_s: jnp.ndarray,

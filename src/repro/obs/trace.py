@@ -11,14 +11,27 @@ hand-off order::
     discovered -> watcher saw the COMMIT marker (event)
     published  -> fleet queue exposed a (step, task) unit (event)
     claimed    -> a worker won the claim race for a unit (event)
+    loaded     -> the worker's snapshot hand-off or durable restore of the
+                  checkpoint's params (span)
     store_build-> TokenStore padding/commit (span)
-    staged     -> host->device staging wait inside one engine run (span)
-    encoded    -> query tower encode (span)
-    scored     -> one full engine run for one (step, task) unit (span)
+    placed     -> params put on the device (or replicated over the mesh)
+    encoded    -> query tower encode, to the embeddings being ready
+    staged     -> the wait for one staged token batch (one span per batch)
+    dispatch   -> one fused encode -> score -> top-k step dispatched
+    drain      -> the wait for the last dispatched step's carry
+    finalize   -> the carry to run lists, with two children:
+                  finalize.fetch (device -> host copy of the carry) and
+                  finalize.build (the run lists, on the host copy)
+    scored     -> one full engine run for one (step, task) unit (span);
+                  the engine's spans above, from placed on, nest under it
+    metrics    -> the verdict's metrics computed from the run lists (span)
     recorded   -> ledger append of the verdict rows (span)
     selected   -> control plane changed its best-step choice (event)
     promoted   -> serving promoter built+verified+installed an index (span)
     served     -> one answered query micro-batch (span)
+
+A host garbage collection that pauses any of them is a ``gc`` span (see
+:class:`repro.obs.Telemetry`); it is not a lifecycle stage.
 
 Trace-record schema (one JSON object per line, mirroring the workqueue
 claim-record docs)::
@@ -35,6 +48,9 @@ claim-record docs)::
   by different fleet worker processes on one host share a timebase, so the
   exporter can merge them into a single timeline without skew correction.
   Monotonic time has an arbitrary epoch — compare within a host/boot only.
+  The same spans reach a ``jax.profiler`` trace, on the device's clock, as
+  ``repro.<name>`` annotations (:class:`repro.obs.Telemetry`), so a
+  profile lines them up with the device operations.
 * ``id`` is unique within one trace file; ``parent`` is the ``id`` of the
   innermost span open *on the same thread* when the record was created
   (``null`` at top level).  Nesting is tracked with a thread-local stack,
@@ -75,14 +91,15 @@ __all__ = ["SpanTracer", "read_trace", "LIFECYCLE_STAGES"]
 #: canonical hand-off order; the exporter sorts same-timestamp records by it
 LIFECYCLE_STAGES: Tuple[str, ...] = (
     "produced", "snapshotted", "discovered", "published", "claimed",
-    "store_build", "staged", "encoded", "scored", "recorded", "selected",
-    "promoted", "served")
+    "loaded", "store_build", "placed", "encoded", "staged", "dispatch",
+    "drain", "finalize", "finalize.fetch", "finalize.build", "scored",
+    "metrics", "recorded", "selected", "promoted", "served")
 
 
 class _Span:
     """Context manager handle; returned by :meth:`SpanTracer.span`."""
 
-    __slots__ = ("tracer", "name", "attrs", "id", "t0")
+    __slots__ = ("tracer", "name", "attrs", "id", "t0", "dur")
 
     def __init__(self, tracer: "SpanTracer", name: str,
                  attrs: Dict[str, Any]):
@@ -91,6 +108,7 @@ class _Span:
         self.attrs = attrs
         self.id: Optional[int] = None
         self.t0 = 0.0
+        self.dur = 0.0
 
     def __enter__(self) -> "_Span":
         self.id = self.tracer._next_id()
@@ -99,7 +117,7 @@ class _Span:
         return self
 
     def __exit__(self, exc_type, exc, tb) -> None:
-        dur = time.monotonic() - self.t0
+        self.dur = dur = time.monotonic() - self.t0
         stack = self.tracer._stack()
         stack.pop()
         parent = stack[-1] if stack else None

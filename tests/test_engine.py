@@ -427,3 +427,154 @@ def test_engine_places_host_params_once(window):
     index = IndexBuilder(spec, {f"d{i}": t for i, t in enumerate(docs)},
                          ServeConfig(k=5, batch_size=8)).build(host, 1)
     assert isinstance(index.params["table"], jax.Array)
+
+
+# ---------------------------------------------------------------------------
+# Telemetry at the engine's boundaries
+# ---------------------------------------------------------------------------
+
+
+def _observed_engine(telemetry, window=4, n_docs=40):
+    spec = EncoderSpec(name="gather", dim=DIM, encode_query=_gather_encode,
+                       encode_passage=_gather_encode, init=None,
+                       q_max_len=2, p_max_len=2)
+    stage = E.StreamTopKStage(_gather_encode, k=5,
+                              query_ids=[f"q{i}" for i in range(5)],
+                              doc_ids=[f"d{i}" for i in range(n_docs)],
+                              window=window)
+    docs = [[i % VOCAB] for i in range(n_docs)]
+    eng = E.StreamingEngine(
+        spec, E.TokenStore.build(docs, max_len=2, chunk=4),
+        E.TokenStore.build([[i] for i in range(5)], max_len=2, chunk=8),
+        stage, telemetry=telemetry)
+    params = {"table": jnp.asarray(np.random.default_rng(0).normal(
+        size=(VOCAB, DIM)), jnp.float32)}
+    return eng, params
+
+
+def test_engine_without_telemetry_opens_no_span_or_hook(monkeypatch):
+    import gc
+
+    import repro.obs as obs
+    calls = []
+    monkeypatch.setattr(obs._Span, "__enter__",
+                        lambda self: calls.append(self.name) or self)
+    monkeypatch.setattr(obs.Telemetry, "register_program",
+                        lambda *a: calls.append("register"))
+    hooks = list(gc.callbacks)
+    eng, params = _observed_engine(None)
+    run, _, timings = eng.run(params)
+    assert len(run) == 5 and timings["retrieve_s"] >= 0
+    assert calls == []
+    assert gc.callbacks == hooks
+    assert eng.stage.telemetry is None
+
+
+def _records(path):
+    from repro.obs import read_trace
+    return read_trace(str(path))
+
+
+def test_engine_spans_staged_per_batch(tmp_path):
+    from repro.obs import Telemetry
+    tel = Telemetry(str(tmp_path / "trace.jsonl"))
+    eng, params = _observed_engine(tel)
+    eng.run(params)
+    tel.flush()
+    recs = _records(tmp_path / "trace.jsonl")
+    schedule = E.plan_schedule(eng.doc_store.n_chunks, 4)
+    staged = [r for r in recs if r["name"] == "staged"]
+    dispatch = [r for r in recs if r["name"] == "dispatch"]
+    assert len(staged) == len(dispatch) == len(schedule) == 3
+    wait = tel.metrics.get("engine.staging_wait_s")
+    assert wait.count == 1
+    assert sum(r["dur"] for r in staged) == pytest.approx(wait.total,
+                                                          rel=1e-12)
+    names = [r["name"] for r in recs]
+    assert names.index("placed") < names.index("encoded") \
+        < names.index("staged") < names.index("drain")
+    assert tel.metrics.get("engine.chunk_step_s") is None
+    assert tel.metrics.get("engine.staging_idle_ratio") is None
+
+
+def test_engine_finalize_spans_nest_and_retrieve_s_covers_them(tmp_path):
+    from repro.obs import Telemetry
+    tel = Telemetry(str(tmp_path / "trace.jsonl"))
+    eng, params = _observed_engine(tel)
+    _, _, timings = eng.run(params)
+    tel.flush()
+    by = {r["name"]: r for r in _records(tmp_path / "trace.jsonl")}
+    fin, fetch, build = (by["finalize"], by["finalize.fetch"],
+                         by["finalize.build"])
+    assert fetch["parent"] == fin["id"] and build["parent"] == fin["id"]
+    assert fetch["t0"] + fetch["dur"] <= build["t0"]
+    assert timings["retrieve_s"] >= fetch["dur"] + build["dur"]
+    # the in-memory copy of every span agrees with the trace file
+    recent = {n: (t0, t1) for n, t0, t1 in tel.recent_spans}
+    assert recent["finalize.build"] == pytest.approx(
+        (build["t0"], build["t0"] + build["dur"]))
+
+
+def test_engine_registers_each_program_once(tmp_path):
+    from repro.obs import SCOPES, Telemetry
+    tel = Telemetry(None)
+    eng, params = _observed_engine(tel, window=4, n_docs=40)
+    eng.run(params)
+    n = len(tel.device_scopes)
+    # 10 chunks at window 4: windows of 4 and 2 — two programs
+    assert len(tel._programs) == 2 and n > 0
+    assert set(tel.device_scopes.values()) <= set(SCOPES)
+    assert tel.device_programs == {"jit_fused_window"}
+    eng.run(params)
+    assert len(tel._programs) == 2 and len(tel.device_scopes) == n
+
+
+def test_hlo_scopes_cover_the_fused_step():
+    """Every non-parameter instruction of the CPU-compiled fused step
+    (encode -> score -> top-k merge of one chunk), outside the scalar
+    reducers and comparators, lies in exactly one of the three scopes, and
+    each scope holds some."""
+    import re
+
+    from repro.models import nn
+    from repro.models.biencoder import biencoder_spec
+    from repro.models.transformer import TransformerConfig
+    from repro.obs import SCOPES, hlo_scopes
+    from repro.obs.scopes import _parse, _profile_text
+    cfg = TransformerConfig(name="tiny", n_layers=1, d_model=32, n_heads=2,
+                            n_kv_heads=2, head_dim=16, d_ff=64,
+                            vocab_size=97, use_rope=False, causal=False,
+                            max_position_embeddings=16, norm_style="post",
+                            act="gelu", qkv_bias=True)
+    spec = biencoder_spec(cfg, pooling="mean", q_max_len=8, p_max_len=16)
+    params = nn.materialize(spec.init(jax.random.PRNGKey(0)))
+    stage = E.StreamTopKStage(spec.encode_passage, k=3,
+                              query_ids=["q0", "q1"],
+                              doc_ids=[f"d{i}" for i in range(8)], window=1)
+    q = jnp.ones((2, spec.dim), jnp.float32)
+    run_s, run_i = stage.init(q)
+    toks = jnp.ones((8, 16), jnp.int32)
+    mask = jnp.ones((8, 16), bool)
+    compiled = stage._fused.lower(params, q, run_s, run_i, toks, mask,
+                                  jnp.int32(0), jnp.int32(8)).compile()
+    text = compiled.as_text()
+    scopes = hlo_scopes(compiled)
+    # scalar reducers and sort comparators run inside another instruction
+    applied = set(re.findall(
+        r"(?:to_apply=|comparator=|called_computations=\{)(%[\w.\-]+)",
+        text))
+    instrs, comp = [], None
+    for line in text.splitlines():
+        head = re.match(r"^(?:ENTRY )?(%[\w.\-]+) .*\{$", line)
+        if head:
+            comp = head.group(1)
+            continue
+        ins = _parse(line)
+        if ins is not None and ins.opcode != "parameter" \
+                and comp not in applied:
+            instrs.append(ins)
+    types = {i.name: i.type for i in map(_parse, text.splitlines()) if i}
+    assert instrs
+    assert all(scopes.get(_profile_text(i, types)) in SCOPES
+               for i in instrs)
+    assert set(scopes.values()) == set(SCOPES)

@@ -199,7 +199,7 @@ def test_threads_do_not_adopt_each_others_spans(tmp_path):
 def test_disabled_telemetry_is_noop(tmp_path):
     tel = Telemetry(None)
     assert tel.tracer is None
-    with tel.span("scored", step=1):    # nullcontext, reusable
+    with tel.span("scored", step=1):    # no tracer: no file
         pass
     with tel.span("scored", step=2):
         pass
@@ -209,6 +209,97 @@ def test_disabled_telemetry_is_noop(tmp_path):
     assert os.listdir(tmp_path) == []   # wrote nothing anywhere
     tel.metrics.counter("still.works").inc()
     assert tel.metrics.get("still.works").value == 1
+
+
+class _Annotation:
+    """Stands in for ``jax.profiler.TraceAnnotation``: logs enter/exit."""
+
+    log: list = []
+
+    def __init__(self, name):
+        self.name = name
+
+    def __enter__(self):
+        self.log.append(("enter", self.name))
+
+    def __exit__(self, *exc):
+        self.log.append(("exit", self.name))
+
+
+def test_span_enters_trace_annotation_and_nests(tmp_path, monkeypatch):
+    import repro.obs as obs
+    monkeypatch.setattr(obs, "_annotation_cls", _Annotation)
+    _Annotation.log = []
+    tel = Telemetry(str(tmp_path / "trace.jsonl"))
+    with tel.span("finalize") as outer:
+        with tel.span("finalize.fetch") as inner:
+            pass
+    assert _Annotation.log == [
+        ("enter", "repro.finalize"), ("enter", "repro.finalize.fetch"),
+        ("exit", "repro.finalize.fetch"), ("exit", "repro.finalize")]
+    tel.flush()
+    recs = {r["name"]: r for r in read_trace(str(tmp_path / "trace.jsonl"))}
+    assert recs["finalize.fetch"]["parent"] == recs["finalize"]["id"]
+    assert outer.dur >= inner.dur > 0
+    assert [n for n, _, _ in tel.recent_spans] == ["finalize.fetch",
+                                                   "finalize"]
+    # a metrics-only handle annotates too, and keeps its recent spans
+    _Annotation.log = []
+    quiet = Telemetry(None)
+    with quiet.span("metrics"):
+        pass
+    assert _Annotation.log == [("enter", "repro.metrics"),
+                               ("exit", "repro.metrics")]
+    assert [n for n, _, _ in quiet.recent_spans] == ["metrics"]
+
+
+def test_span_is_seen_by_the_profiler(tmp_path):
+    import glob
+
+    from jax.profiler import ProfileData
+    tel = Telemetry(None)
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with tel.span("drain"):
+            time.sleep(0.002)
+    finally:
+        jax.profiler.stop_trace()
+    path = glob.glob(str(tmp_path / "plugins" / "profile" / "*" /
+                         "*.xplane.pb"))[0]
+    names = {e.name for plane in ProfileData.from_file(path).planes
+             for line in plane.lines for e in line.events}
+    assert "repro.drain" in names
+
+
+def test_gc_hook_times_collections_while_telemetry_lives():
+    import gc
+
+    import repro.obs as obs
+    gc.collect()
+    tel = Telemetry(None)
+    assert obs._gc_hook in gc.callbacks
+    gc.collect()
+    hist = tel.metrics.get(obs.GC_PAUSE_METRIC)
+    assert hist is not None and hist.count >= 1
+    assert any(n == "gc" for n, _, _ in tel.recent_spans)
+    assert tel in obs.live_telemetry()
+    # no hook before the first handle nor after the last (a fresh process:
+    # other tests here may keep handles alive)
+    import subprocess
+    import sys
+    code = ("import gc, repro.obs as o\n"
+            "assert o._gc_hook not in gc.callbacks\n"
+            "t = o.Telemetry(None)\n"
+            "assert o._gc_hook in gc.callbacks\n"
+            "del t\n"
+            "gc.collect()\n"
+            "assert o._gc_hook not in gc.callbacks and not o.live_telemetry()\n")
+    src = os.path.dirname(os.path.dirname(os.path.abspath(obs.__file__)))
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               PYTHONPATH=os.pathsep.join(
+                   p for p in (src, os.environ.get("PYTHONPATH")) if p))
+    subprocess.run([sys.executable, "-c", code], check=True, env=env,
+                   timeout=120)
 
 
 def test_mark_since_cross_stage_latency():
@@ -523,8 +614,9 @@ def test_two_worker_fleet_trace_attribution(ds, tmp_path):
 def test_lifecycle_vocabulary_is_stable():
     assert LIFECYCLE_STAGES == (
         "produced", "snapshotted", "discovered", "published", "claimed",
-        "store_build", "staged", "encoded", "scored", "recorded",
-        "selected", "promoted", "served")
+        "loaded", "store_build", "placed", "encoded", "staged", "dispatch",
+        "drain", "finalize", "finalize.fetch", "finalize.build", "scored",
+        "metrics", "recorded", "selected", "promoted", "served")
 
 
 def test_obs_report_prints_verdict_percentiles(capsys):
