@@ -606,7 +606,7 @@ class StreamTopKStage(Stage):
                 else:
                     s = chunk_scores(q_emb, emb, score_dtype)  # (Q, chunk)
                 s = jnp.where((col < n_valid)[None, :], s, -jnp.inf)
-            # single top_k over [carry ‖ chunk]: selecting top-k of the union
+            # one top-k sort over [carry ‖ chunk]: selecting top-k of the union
             # directly is identical to local-top-k-then-merge (top-k of a set
             # equals top-k of carry ∪ top-k(chunk)) but does one sort of
             # width k+chunk instead of two of width chunk and 2k.
@@ -723,7 +723,7 @@ class ShardedStreamTopKStage(StreamTopKStage):
             with jax.named_scope("merge"):
                 kk = min(k_carry, rows)
                 bs, pos = jax.lax.top_k(s, kk)
-                bi = jnp.take(col, pos) + base                # global doc rows
+                bi = shard * rows + pos + base                # global doc rows
                 bs, bi = _hierarchical_topk_merge(bs, bi, axis_names,
                                                   k_carry)
                 return _merge_topk(run_s, run_i, bs, bi, k_carry)

@@ -28,14 +28,39 @@ from jax.sharding import PartitionSpec as P
 from repro.core.precision import chunk_scores, validate_score_dtype
 
 
+def _topk_with_ids(s, i, k: int):
+    """Top ``k`` of a (Q, W) score matrix, with the (Q, W) ids beside them.
+
+    The ids ride the score sort as its second key, so nothing is gathered
+    after it: ``lax.top_k`` sorts the scores with an iota and then looks
+    each of the Q x k positions up in ``i``, a gather that costs far more
+    than the sort at k in the hundreds.  Equal scores come out by ascending
+    id.  That is ``lax.top_k``'s position order wherever ids ascend with
+    position among equal scores, and every caller feeds it so: a fold's
+    carry holds the earlier chunks' ids (or the empty carry's 0) ahead of
+    the chunk's ``base + col``, and each sorted output keeps ids ascending
+    among ties for the next fold; the sharded merge gathers shard-major
+    lists whose ids ascend with the shard.  Entries equal in score and id
+    are the empty carry's padding, alike bit for bit.  A stable one-key
+    sort would keep position order without that argument, but the stable
+    tie-break is a third sort operand that costs more time on the TPU than
+    the second key.  The one departure is signed zeros: ``lax.top_k``
+    ranks ``+0.0`` above ``-0.0``, while here they tie and keep position
+    order, as the Pallas merge (``_merge_tile``) has them.  Negation is
+    exact, so the scores come back bit for bit."""
+    neg, ids = jax.lax.sort((-s, i), dimension=1, num_keys=2,
+                            is_stable=False)
+    return -neg[:, :k], ids[:, :k]
+
+
 def _merge_topk(scores_a, idx_a, scores_b, idx_b, k: int):
     """Merge two (Q, ka/kb) candidate sets into (Q, k), under the
-    ``merge`` scope that device traces attribute the fold by."""
+    ``merge`` scope that device traces attribute the fold by.  Ties keep
+    position order: ``a`` before ``b``."""
     with jax.named_scope("merge"):
         s = jnp.concatenate([scores_a, scores_b], axis=1)
         i = jnp.concatenate([idx_a, idx_b], axis=1)
-        top_s, pos = jax.lax.top_k(s, k)
-        return top_s, jnp.take_along_axis(i, pos, axis=1)
+        return _topk_with_ids(s, i, k)
 
 
 @functools.partial(jax.jit, static_argnames=("k", "block", "unroll",
@@ -97,8 +122,7 @@ def _hierarchical_topk_merge(s, i, axis_names, k: int):
         Sn = all_s.shape[0] * all_s.shape[2]
         flat_s = jnp.moveaxis(all_s, 0, 1).reshape(s.shape[0], Sn)
         flat_i = jnp.moveaxis(all_i, 0, 1).reshape(s.shape[0], Sn)
-        s, pos = jax.lax.top_k(flat_s, min(k, Sn))
-        i = jnp.take_along_axis(flat_i, pos, axis=1)
+        s, i = _topk_with_ids(flat_s, flat_i, min(k, Sn))
     return s, i
 
 

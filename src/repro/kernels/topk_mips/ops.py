@@ -142,11 +142,9 @@ def _merge_carry(run_s, run_i, chunk_s, chunk_i, base, *, k: int):
     """Fold a chunk-local top-k (indices relative to the chunk) into the
     running (Q, k) carry.  ``base`` is dynamic — one compile per chunk shape,
     not per chunk position."""
-    with jax.named_scope("merge"):
-        s = jnp.concatenate([run_s, chunk_s], axis=1)
-        i = jnp.concatenate([run_i, chunk_i + base], axis=1)
-        top_s, pos = jax.lax.top_k(s, k)
-        return top_s, jnp.take_along_axis(i, pos, axis=1)
+    # imported here: repro.core.precision imports this module
+    from repro.core.retrieval import _merge_topk
+    return _merge_topk(run_s, run_i, chunk_s, chunk_i + base, k)
 
 
 def topk_mips_chunk(q: jnp.ndarray, c_chunk: jnp.ndarray, run_s: jnp.ndarray,
