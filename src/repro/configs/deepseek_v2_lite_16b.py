@@ -2,10 +2,11 @@
 
 27L d_model=2048 16H, MLA kv_lora=512 (qk_nope=128, qk_rope=64, v=128),
 MoE: 64 routed experts top-6 + 2 shared, expert d_ff=1408, first layer dense
-(d_ff=10944), vocab=102400.
-
-NOTE (DESIGN.md §4): the assignment line mentions both "64e top-6" and
-"2 shared+160 routed"; 160 routed belongs to full V2 — lite is 64 routed.
+(d_ff=10944), vocab=102400; softmax routing with the top-6 gates not
+renormalised (norm_topk_prob false), YaRN rope scaling (factor 40 over 4,096
+original positions, beta 32/1, mscale and mscale_all_dim 0.707), as the
+published config.json (deepseek-ai/DeepSeek-V2-Lite) states.  Lite has 64
+routed experts; the 160 routed belong to the full V2.
 """
 
 from repro.configs.registry import LM_SHAPES
@@ -13,6 +14,11 @@ from repro.models.transformer import TransformerConfig
 
 FAMILY = "lm"
 SHAPES = LM_SHAPES
+
+#: rope_scaling of the published config
+_YARN = dict(yarn_factor=40.0, yarn_original_max_position=4096,
+             yarn_beta_fast=32.0, yarn_beta_slow=1.0, yarn_mscale=0.707,
+             yarn_mscale_all_dim=0.707)
 
 
 def full_config() -> TransformerConfig:
@@ -23,7 +29,8 @@ def full_config() -> TransformerConfig:
         mla=True, kv_lora_rank=512, qk_nope_dim=128, qk_rope_dim=64,
         v_head_dim=128,
         moe_num_experts=64, moe_top_k=6, moe_d_ff=1408, moe_num_shared=2,
-        first_k_dense=1, moe_mode="replace", q_chunk=512)
+        first_k_dense=1, moe_mode="replace", q_chunk=512, norm_eps=1e-6,
+        norm_topk_prob=False, **_YARN)
 
 
 def smoke_config() -> TransformerConfig:
@@ -32,4 +39,5 @@ def smoke_config() -> TransformerConfig:
         n_kv_heads=4, head_dim=16, d_ff=128, vocab_size=211, act="swiglu",
         mla=True, kv_lora_rank=32, qk_nope_dim=16, qk_rope_dim=8, v_head_dim=16,
         moe_num_experts=8, moe_top_k=2, moe_d_ff=48, moe_num_shared=2,
-        first_k_dense=1, moe_mode="replace", q_chunk=16)
+        first_k_dense=1, moe_mode="replace", q_chunk=16,
+        norm_topk_prob=False, **_YARN)

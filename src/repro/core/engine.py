@@ -69,6 +69,7 @@ from repro.core.retrieval import (_hierarchical_slot_max,
                                   pad_candidates, rank_candidates, rerank_run,
                                   retrieve_run)
 from repro.data.corpus import Tokens, pad_batch
+from repro.obs import HELD_ASSIGNMENTS_METRIC
 
 Run = Dict[str, List[str]]
 Scores = Dict[str, List[float]]
@@ -576,24 +577,35 @@ class StreamTopKStage(Stage):
     per-chunk math in the same order (parity is preserved bit for bit), but
     the Python/dispatch overhead amortizes ``window``-fold.  Token staging
     grows to O(window x chunk x L); embeddings stay O(chunk x D).
+
+    With ``encode_counted`` (an encoder that also returns the int32 count of
+    routed (token, held expert) assignments) the carry gains that count,
+    summed over the stream on the device, and ``finalize`` records it in the
+    telemetry's ``moe.held_assignments`` histogram, one observation per
+    stream.
     """
 
     name = "topk_xla"
 
     def __init__(self, encode_fn: Callable, *, k: int, query_ids: List[str],
                  doc_ids: List[str], window: int = 8,
-                 score_dtype: str = "f32"):
+                 score_dtype: str = "f32",
+                 encode_counted: Optional[Callable] = None):
         self.query_ids = query_ids
         self.doc_ids = doc_ids
         self.k = max(1, min(k, len(doc_ids))) if doc_ids else 0
         self.window = max(1, window)
         self.score_dtype = validate_score_dtype(score_dtype)
+        self.counted = encode_counted is not None
         k_carry = self.k
 
         def fold(carry, q_emb, params, toks, mask, base, n_valid):
-            run_s, run_i = carry
+            run_s, run_i = carry[:2]
             with jax.named_scope("encode"):
-                emb = encode_fn(params, toks, mask)           # (chunk, D)
+                if encode_counted is None:
+                    emb = encode_fn(params, toks, mask)       # (chunk, D)
+                else:
+                    emb, held = encode_counted(params, toks, mask)
             chunk = toks.shape[0]
             col = jnp.arange(chunk, dtype=jnp.int32)
             with jax.named_scope("score"):
@@ -612,19 +624,22 @@ class StreamTopKStage(Stage):
             # width k+chunk instead of two of width chunk and 2k.
             with jax.named_scope("merge"):
                 gcol = jnp.broadcast_to((col + base)[None, :], s.shape)
-                return _merge_topk(run_s, run_i, s, gcol, k_carry)
+                merged = _merge_topk(run_s, run_i, s, gcol, k_carry)
+            return merged if encode_counted is None \
+                else merged + (carry[2] + held,)
 
-        def fused(params, q_emb, run_s, run_i, toks, mask, base, n_valid):
-            return fold((run_s, run_i), q_emb, params, toks, mask, base,
-                        n_valid)
+        def fused(params, q_emb, run_s, run_i, toks, mask, base, n_valid,
+                  *held):
+            return fold((run_s, run_i) + held, q_emb, params, toks, mask,
+                        base, n_valid)
 
         def fused_window(params, q_emb, run_s, run_i, toks_w, mask_w,
-                         bases, n_valids):
+                         bases, n_valids, *held):
             def body(carry, inp):
                 toks, mask, base, n_valid = inp
                 return fold(carry, q_emb, params, toks, mask, base,
                             n_valid), None
-            carry, _ = jax.lax.scan(body, (run_s, run_i),
+            carry, _ = jax.lax.scan(body, (run_s, run_i) + held,
                                     (toks_w, mask_w, bases, n_valids))
             return carry
 
@@ -634,25 +649,30 @@ class StreamTopKStage(Stage):
 
     def init(self, q_emb):
         Q = q_emb.shape[0]
-        return (jnp.full((Q, self.k), -jnp.inf, jnp.float32),
-                jnp.zeros((Q, self.k), jnp.int32))
+        carry = (jnp.full((Q, self.k), -jnp.inf, jnp.float32),
+                 jnp.zeros((Q, self.k), jnp.int32))
+        return carry + (jnp.zeros((), jnp.int32),) if self.counted else carry
 
     def step(self, params, q_emb, carry, toks, mask, base, n_valid):
-        run_s, run_i = carry
+        run_s, run_i, *held = carry
         return self._call(self._fused, params, q_emb, run_s, run_i, toks,
                           mask, jnp.asarray(base, jnp.int32),
-                          jnp.asarray(n_valid, jnp.int32))
+                          jnp.asarray(n_valid, jnp.int32), *held)
 
     def step_window(self, params, q_emb, carry, toks_w, mask_w, bases,
                     n_valids):
         """Fold ``window`` chunks in one dispatch (scan inside the jit)."""
-        run_s, run_i = carry
+        run_s, run_i, *held = carry
         return self._call(self._fused_window, params, q_emb, run_s, run_i,
                           toks_w, mask_w, jnp.asarray(bases, jnp.int32),
-                          jnp.asarray(n_valids, jnp.int32))
+                          jnp.asarray(n_valids, jnp.int32), *held)
 
     def finalize(self, carry):
         run_s, run_i = np.asarray(carry[0]), np.asarray(carry[1])
+        tel = self.telemetry
+        if self.counted and tel is not None:
+            tel.metrics.histogram(HELD_ASSIGNMENTS_METRIC).observe(
+                int(carry[2]))
         run, scores = {}, {}
         for qi, qid in enumerate(self.query_ids):
             run[qid] = [self.doc_ids[j] for j in run_i[qi]]
@@ -1372,13 +1392,28 @@ def make_streaming_engine(spec, store: ValidationStore, vcfg):
     if query_store is None:
         query_store = TokenStore.build(store.query_texts,
                                        max_len=spec.q_max_len, chunk=q_chunk)
-    stage = make_stage(spec.encode_passage, mode=vcfg.mode, impl=vcfg.impl,
-                       k=vcfg.k, query_ids=store.query_ids,
-                       doc_ids=store.doc_ids, per_query=store.per_query,
-                       mesh=mesh, scan_window=vcfg.scan_window,
-                       store=doc_store,
-                       score_dtype=getattr(vcfg, "score_dtype", "f32"),
-                       rerank_compact=getattr(vcfg, "rerank_compact", True))
+    score_dtype = getattr(vcfg, "score_dtype", "f32")
+    counted = getattr(spec, "encode_passage_counted", None)
+    if counted is not None and MODES.get(vcfg.mode)(
+            impl=vcfg.impl, mesh=mesh,
+            per_query=store.per_query) == "topk_xla":
+        # the single-device retrieval stage carries an expert trunk's count
+        # of routed work; the other stages encode without it
+        stage = StreamTopKStage(spec.encode_passage, k=vcfg.k,
+                                query_ids=store.query_ids,
+                                doc_ids=store.doc_ids,
+                                window=vcfg.scan_window,
+                                score_dtype=score_dtype,
+                                encode_counted=counted)
+    else:
+        stage = make_stage(spec.encode_passage, mode=vcfg.mode,
+                           impl=vcfg.impl, k=vcfg.k,
+                           query_ids=store.query_ids, doc_ids=store.doc_ids,
+                           per_query=store.per_query, mesh=mesh,
+                           scan_window=vcfg.scan_window, store=doc_store,
+                           score_dtype=score_dtype,
+                           rerank_compact=getattr(vcfg, "rerank_compact",
+                                                  True))
     return StreamingEngine(spec, doc_store, query_store, stage,
                            staging=vcfg.staging,
                            staging_depth=vcfg.staging_depth, query_mesh=mesh,

@@ -40,6 +40,10 @@ class EncoderSpec:
     init: Callable[[Any], Any]                      # rng -> Param tree
     q_max_len: int = 32
     p_max_len: int = 128
+    # encode_passage that also returns the int32 count of routed
+    # (token, held expert) assignments; set for a trunk with expert layers,
+    # and used by the single-device retrieval stage (topk_xla) alone
+    encode_passage_counted: Optional[Callable] = None
 
 
 def biencoder_spec(cfg: tfm.TransformerConfig, *, pooling: str = "cls",
@@ -49,10 +53,15 @@ def biencoder_spec(cfg: tfm.TransformerConfig, *, pooling: str = "cls",
     def enc(params, tokens, mask):
         return tfm.encode(params, cfg, tokens, mask, pooling)
 
+    def enc_counted(params, tokens, mask):
+        return tfm.encode(params, cfg, tokens, mask, pooling, count_held=True)
+
     return EncoderSpec(name=cfg.name, dim=cfg.d_model,
                        encode_query=enc, encode_passage=enc,
                        init=lambda rng: tfm.init(rng, cfg),
-                       q_max_len=q_max_len, p_max_len=p_max_len)
+                       q_max_len=q_max_len, p_max_len=p_max_len,
+                       encode_passage_counted=(enc_counted if cfg.is_moe
+                                               else None))
 
 
 def contrastive_loss(params, spec: EncoderSpec, batch, *, temperature: float = 1.0):

@@ -248,17 +248,58 @@ def rope_frequencies(head_dim: int, theta: float = 10000.0) -> jnp.ndarray:
     return 1.0 / (theta ** exponent)
 
 
-def apply_rope(x: jnp.ndarray, positions: jnp.ndarray, theta: float = 10000.0):
+def yarn_correction_range(head_dim: int, theta: float, original_max: int,
+                          beta_fast: float, beta_slow: float):
+    """YaRN's (low, high) frequency-pair indices (Peng et al.,
+    arXiv:2309.00071): pairs below ``low`` turn more than ``beta_fast``
+    times over ``original_max`` positions and keep their frequency; pairs
+    from ``high`` up turn fewer than ``beta_slow`` times and are
+    interpolated."""
+    def corr(rotations: float) -> float:
+        return (head_dim * math.log(original_max / (rotations * 2 * math.pi))
+                / (2 * math.log(theta)))
+    return (max(math.floor(corr(beta_fast)), 0),
+            min(math.ceil(corr(beta_slow)), head_dim - 1))
+
+
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's attention-temperature factor ``0.1 * mscale * ln(factor) + 1``
+    (1 when the context is not extended)."""
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def yarn_frequencies(head_dim: int, theta: float, factor: float,
+                     original_max: int, beta_fast: float,
+                     beta_slow: float) -> jnp.ndarray:
+    """YaRN inverse frequencies, shape (head_dim // 2,), float32: pair ``i``
+    blends ``theta_i / factor`` and ``theta_i`` by a linear ramp between the
+    correction dims (DeepSeek-V2's ``DeepseekV2YarnRotaryEmbedding``)."""
+    low, high = yarn_correction_range(head_dim, theta, original_max,
+                                      beta_fast, beta_slow)
+    extra = rope_frequencies(head_dim, theta)
+    ramp = jnp.clip((jnp.arange(head_dim // 2, dtype=jnp.float32) - low)
+                    / max(high - low, 0.001), 0.0, 1.0)
+    keep = 1.0 - ramp
+    return extra / factor * (1.0 - keep) + extra * keep
+
+
+def apply_rope(x: jnp.ndarray, positions: jnp.ndarray, theta: float = 10000.0,
+               inv_freq: Optional[jnp.ndarray] = None, scale: float = 1.0):
     """Rotary embedding.
 
     x: (..., seq, n_heads, head_dim); positions: broadcastable to (..., seq).
     Pairs dimension d with d + head_dim//2 (the "rotate_half" convention).
+    ``inv_freq`` replaces the plain frequencies (YaRN's); ``scale``
+    multiplies cos and sin.
     """
     head_dim = x.shape[-1]
-    inv_freq = rope_frequencies(head_dim, theta)  # (hd/2,)
+    if inv_freq is None:
+        inv_freq = rope_frequencies(head_dim, theta)  # (hd/2,)
     angles = positions[..., None].astype(jnp.float32) * inv_freq  # (..., seq, hd/2)
     cos = jnp.cos(angles)[..., None, :]  # (..., seq, 1, hd/2)
     sin = jnp.sin(angles)[..., None, :]
+    if scale != 1.0:
+        cos, sin = cos * scale, sin * scale
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1)
     return out.astype(x.dtype)

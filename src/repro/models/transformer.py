@@ -22,6 +22,7 @@ Design notes
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any, Optional
 
 import jax
@@ -62,12 +63,25 @@ class TransformerConfig:
     moe_capacity_factor: float = 1.25
     first_k_dense: int = 0
     router_aux_coef: float = 0.01
+    norm_topk_prob: bool = True         # renormalise the top-k gates to sum 1
+    # the chip's share of each expert layer (expert parallelism): the router
+    # scores all moe_num_experts, w1/w3/w2 hold experts
+    # [moe_first_held, moe_first_held + moe_experts_held); 0 holds them all
+    moe_experts_held: int = 0
+    moe_first_held: int = 0
     # --- MLA ---
     mla: bool = False
     kv_lora_rank: int = 0
     qk_nope_dim: int = 128
     qk_rope_dim: int = 64
     v_head_dim: int = 128
+    # --- YaRN rope scaling of MLA (DeepSeek-V2 rope_scaling); 0 = plain RoPE
+    yarn_factor: float = 0.0
+    yarn_original_max_position: int = 4096
+    yarn_beta_fast: float = 32.0
+    yarn_beta_slow: float = 1.0
+    yarn_mscale: float = 1.0
+    yarn_mscale_all_dim: float = 0.0
     # --- execution ---
     param_dtype: Any = jnp.float32
     compute_dtype: Any = jnp.bfloat16
@@ -75,7 +89,7 @@ class TransformerConfig:
     q_chunk: int = 512                  # attention query-chunk size
     vocab_chunk: int = 0                # 0 = full logits; >0 = chunked xent
     attn_impl: str = "xla"              # xla | pallas (TPU target)
-    # --- cost-extraction unrolls (roofline methodology, DESIGN.md §2.7) ---
+    # --- cost-extraction unrolls (roofline methodology) ---
     # XLA's cost_analysis counts a while(scan) body ONCE, not x trip-count;
     # the dry-run's cost-extraction variant fully unrolls every inner scan
     # (layers / attention q-chunks / vocab chunks) at reduced depth so
@@ -98,6 +112,11 @@ class TransformerConfig:
     @property
     def is_moe(self) -> bool:
         return self.moe_num_experts > 0
+
+    @property
+    def experts_held(self) -> int:
+        """Routed experts whose weights this layer holds."""
+        return self.moe_experts_held or self.moe_num_experts
 
 
 # ---------------------------------------------------------------------------
@@ -152,8 +171,13 @@ def _dense_mlp_init(rng, cfg: TransformerConfig, d_ff: int):
 def _moe_init(rng, cfg: TransformerConfig):
     rngs = nn.split_rngs(rng, 5)
     D, E, F, dt = cfg.d_model, cfg.moe_num_experts, cfg.moe_d_ff, cfg.param_dtype
+    if cfg.moe_first_held + cfg.experts_held > E:
+        raise ValueError(f"experts {cfg.moe_first_held}.."
+                         f"{cfg.moe_first_held + cfg.experts_held - 1} held "
+                         f"of {E}")
     p = {"router": nn.normal_init(rngs[0], (D, E), ("embed", "expert"),
                                   stddev=0.02, dtype=jnp.float32)}
+    E = cfg.experts_held
     p["w1"] = nn.fanin_init(rngs[1], (E, D, F), ("expert", "embed", "mlp"),
                             fan_in=D, dtype=dt)
     p["w3"] = nn.fanin_init(rngs[2], (E, D, F), ("expert", "embed", "mlp"),
@@ -234,18 +258,21 @@ def _norm(p, x, cfg):
 
 
 def _chunked_attention(q, k, v, *, causal: bool, q_offset, q_chunk: int,
-                       kv_mask=None, unroll: int = 1):
+                       kv_mask=None, unroll: int = 1,
+                       scale: Optional[float] = None):
     """Grouped-query attention computed in query chunks.
 
     q: (B, S, KV, G, hd) ; k, v: (B, T, KV, hd).
     q_offset: scalar — absolute position of q[0] (for causal masking in decode).
     kv_mask: optional (B, T) validity mask.
+    scale: softmax scale of the logits (default 1/sqrt(hd)).
     Returns (B, S, KV, G, hd).
     """
     B, S, KV, G, hd = q.shape
     T = k.shape[1]
     dv = v.shape[-1]  # may differ from hd (MLA)
-    scale = jnp.asarray(1.0 / (hd ** 0.5), jnp.float32)
+    scale = jnp.asarray(1.0 / (hd ** 0.5) if scale is None else scale,
+                        jnp.float32)
     nq = max(1, min(q_chunk, S))
     n_chunks = -(-S // nq)
     pad = n_chunks * nq - S
@@ -357,6 +384,21 @@ def _attention(p, x, cfg: TransformerConfig, *, positions, cache=None,
     return out, new_cache
 
 
+def _mla_rope(x, positions, cfg: TransformerConfig):
+    """MLA's rotary embedding: YaRN's frequencies and cos/sin scale when
+    ``cfg.yarn_factor`` is set, else plain RoPE."""
+    if not cfg.yarn_factor:
+        return nn.apply_rope(x, positions, cfg.rope_theta)
+    inv_freq = nn.yarn_frequencies(
+        x.shape[-1], cfg.rope_theta, cfg.yarn_factor,
+        cfg.yarn_original_max_position, cfg.yarn_beta_fast,
+        cfg.yarn_beta_slow)
+    scale = (nn.yarn_mscale(cfg.yarn_factor, cfg.yarn_mscale)
+             / nn.yarn_mscale(cfg.yarn_factor, cfg.yarn_mscale_all_dim))
+    return nn.apply_rope(x, positions, cfg.rope_theta, inv_freq=inv_freq,
+                         scale=scale)
+
+
 def _mla_attention(p, x, cfg: TransformerConfig, *, positions, cache=None,
                    cache_index=None, kv_mask=None):
     """Multi-head latent attention (DeepSeek-V2). Cache stores (c_kv, k_rope).
@@ -373,14 +415,15 @@ def _mla_attention(p, x, cfg: TransformerConfig, *, positions, cache=None,
 
     q = (x @ p["wq"].astype(cd)).reshape(B, S, H, dn + dr)
     q_nope, q_rope = q[..., :dn], q[..., dn:]
-    q_rope = nn.apply_rope(q_rope, positions, cfg.rope_theta)
+    q_rope = _mla_rope(q_rope, positions, cfg)
 
     dkv = x @ p["wdkv"].astype(cd)                    # (B, S, R + dr)
     c_kv = nn.rmsnorm(p["kv_norm"], dkv[..., :R], cfg.norm_eps)
     k_rope = dkv[..., R:].reshape(B, S, 1, dr)
-    k_rope = nn.apply_rope(k_rope, positions, cfg.rope_theta)
+    k_rope = _mla_rope(k_rope, positions, cfg)
 
-    scale = 1.0 / jnp.sqrt(jnp.asarray(dn + dr, jnp.float32))
+    scale = (1.0 / (dn + dr) ** 0.5
+             * nn.yarn_mscale(cfg.yarn_factor, cfg.yarn_mscale_all_dim) ** 2)
 
     if cache is not None and S == 1:
         # ---- absorbed decode (attention directly in the compressed space) ----
@@ -436,7 +479,7 @@ def _mla_attention(p, x, cfg: TransformerConfig, *, positions, cache=None,
         out = _chunked_attention(qg, k, vv, causal=cfg.causal,
                                  q_offset=q_offset,
                                  q_chunk=cfg.q_chunk, kv_mask=kv_mask,
-                                 unroll=cfg.attn_unroll)
+                                 unroll=cfg.attn_unroll, scale=scale)
         out = out.reshape(B, S, H, dv)
 
     out = out.reshape(B, S, H * dv) @ p["wo"].astype(cd)
@@ -491,12 +534,16 @@ def _moe_block(p, x, cfg: TransformerConfig):
     B, S, D = x.shape
     E, K = cfg.moe_num_experts, cfg.moe_top_k
     cd = cfg.compute_dtype
+    if cfg.experts_held != E:
+        raise ValueError("the capacity form computes every expert; a layer "
+                         "that holds a share of them runs on the encode path")
     capacity = max(1, int(S * K / E * cfg.moe_capacity_factor))
 
     logits = (x.astype(jnp.float32) @ p["router"].astype(jnp.float32))  # (B,S,E)
     probs = jax.nn.softmax(logits, axis=-1)
     gates, expert_idx = jax.lax.top_k(probs, K)                          # (B,S,K)
-    gates = gates / jnp.clip(gates.sum(-1, keepdims=True), 1e-9)
+    if cfg.norm_topk_prob:
+        gates = gates / jnp.clip(gates.sum(-1, keepdims=True), 1e-9)
 
     # load-balancing aux loss (GShard): E * sum_e f_e * p_e
     me = jnp.mean(probs, axis=(0, 1))                                    # (E,)
@@ -542,13 +589,197 @@ def _moe_block(p, x, cfg: TransformerConfig):
     return out, aux
 
 
+def _gmm_interpret() -> bool:
+    """Run the grouped product's Pallas kernel in interpret mode (the CPU)."""
+    return jax.default_backend() == "cpu"
+
+
+def _gmm_tiling(m: int, k: int, n: int):
+    """Tiles of the grouped products: all ``m`` rows (at most 512), and
+    whole ``k`` and ``n`` up to 1,536, else 512 (fast memory stays under 16
+    MiB at DeepSeek-V2's widths)."""
+    return (m, k if k <= 1536 else 512, n if n <= 1536 else 512)
+
+
+def _gmm(lhs, rhs, group_sizes, out_dtype, transpose_rhs: bool = False):
+    """Grouped product ``lhs (m, k)`` x ``rhs (g, k, n)`` (``(g, n, k)``
+    with ``transpose_rhs``): rows of group ``i`` (consecutive,
+    ``group_sizes[i]`` of them from the top) times ``rhs[i]``; rows past
+    the last group are left unwritten.  Megablox's Pallas ``gmm``, whose
+    grid visits only the tiles that hold rows, under the ``experts``
+    scope."""
+    from jax.experimental.pallas.ops.tpu.megablox import gmm
+    m, k = lhs.shape
+    n = rhs.shape[1 if transpose_rhs else 2]
+    with jax.named_scope("experts"):
+        return gmm(lhs, rhs, group_sizes, out_dtype, _gmm_tiling(m, k, n),
+                   transpose_rhs=transpose_rhs, interpret=_gmm_interpret())
+
+
+def _tgmm(lhs, rhs, group_sizes, out_dtype):
+    """Per group, ``lhs[rows].T @ rhs[rows]`` over the group's rows of
+    ``lhs (m, k)`` and ``rhs (m, n)`` -> ``(g, k, n)``: the weight gradient
+    of :func:`_gmm`.  Rows outside every group are not read."""
+    from jax.experimental.pallas.ops.tpu.megablox.gmm import tgmm
+    m, k = lhs.shape
+    with jax.named_scope("experts"):
+        return tgmm(lhs.swapaxes(0, 1), rhs, group_sizes, out_dtype,
+                    _gmm_tiling(m, k, rhs.shape[1]),
+                    interpret=_gmm_interpret())
+
+
+def _expert_block(lo, blk, tok, starts, sizes):
+    """Block ``[lo, lo + blk)`` of the sorted assignments: its tokens' rows
+    and each held expert's count of rows in it (the grouped products'
+    group sizes)."""
+    rows = jax.lax.dynamic_slice_in_dim(tok, lo, blk)
+    part = jnp.maximum(jnp.minimum(starts + sizes, lo + blk)
+                       - jnp.maximum(starts, lo), 0)
+    return rows, part
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _held_experts(blk, xf, w1, w3, w2, gate, tok, starts, sizes, n):
+    """The held experts' gated SwiGLU outputs summed into each token's row,
+    (N, D) float32.
+
+    The ``n`` live assignments, sorted by held expert (``starts``/``sizes``
+    each expert's span), are computed in blocks of ``blk``, as many as
+    hold live ones: gather the block's tokens ``xf[tok]``, one grouped
+    product per matrix, gate by ``gate``, scatter-add.  The trip count
+    follows ``n``, so the loop is a ``while`` that reverse mode cannot
+    differentiate; the rule below runs the same blocks backwards by hand.
+    """
+    cd = xf.dtype
+
+    def block(i, out):
+        lo = i * blk
+        rows, part = _expert_block(lo, blk, tok, starts, sizes)
+        xb = xf[rows]
+        h = nn.silu(_gmm(xb, w1, part, cd)) * _gmm(xb, w3, part, cd)
+        y = _gmm(h, w2, part, jnp.float32)
+        live = lo + jnp.arange(blk) < n
+        g = jax.lax.dynamic_slice_in_dim(gate, lo, blk)
+        y = jnp.where(live[:, None], y * g[:, None], 0.0)
+        return out.at[rows].add(y)
+
+    return jax.lax.fori_loop(0, -(-n // blk), block,
+                             jnp.zeros(xf.shape, jnp.float32))
+
+
+def _held_experts_fwd(blk, *args):
+    return _held_experts(blk, *args), args
+
+
+def _held_experts_bwd(blk, res, ct):
+    """Each live block again, recomputed: the cotangents of its tokens'
+    rows (scatter-added), of the three weights (accumulated in float32 by
+    :func:`_tgmm`) and of its gates.  Rows past the live ones hold whatever
+    the unwritten grouped-product rows held, so they are selected away,
+    never multiplied by zero."""
+    xf, w1, w3, w2, gate, tok, starts, sizes, n = res
+    cd = xf.dtype
+
+    def block(i, acc):
+        dx, dw1, dw3, dw2, dgate = acc
+        lo = i * blk
+        rows, part = _expert_block(lo, blk, tok, starts, sizes)
+        live = (lo + jnp.arange(blk) < n)[:, None]
+        g = jax.lax.dynamic_slice_in_dim(gate, lo, blk)
+        xb = xf[rows]
+        a, b = _gmm(xb, w1, part, cd), _gmm(xb, w3, part, cd)
+        s, silu_vjp = jax.vjp(nn.silu, a)
+        h = s * b
+        y = _gmm(h, w2, part, jnp.float32)
+        cy = jnp.where(live, ct[rows], 0.0)
+        dg = jnp.where(live[:, 0], jnp.sum(cy * y, axis=1), 0.0)
+        dy = (cy * g[:, None]).astype(cd)
+        dh = _gmm(dy, w2, part, cd, transpose_rhs=True)
+        da, db = silu_vjp(dh * b)[0], dh * s
+        dxb = (_gmm(da, w1, part, jnp.float32, transpose_rhs=True)
+               + _gmm(db, w3, part, jnp.float32, transpose_rhs=True))
+        return (dx.at[rows].add(jnp.where(live, dxb, 0.0)),
+                dw1 + _tgmm(xb, da, part, jnp.float32),
+                dw3 + _tgmm(xb, db, part, jnp.float32),
+                dw2 + _tgmm(h, dy, part, jnp.float32),
+                jax.lax.dynamic_update_slice_in_dim(
+                    dgate, dg.astype(dgate.dtype), lo, 0))
+
+    zeros = lambda a: jnp.zeros(a.shape, jnp.float32)
+    dx, dw1, dw3, dw2, dgate = jax.lax.fori_loop(
+        0, -(-n // blk), block,
+        (zeros(xf), zeros(w1), zeros(w3), zeros(w2), jnp.zeros_like(gate)))
+    return (dx.astype(xf.dtype), dw1.astype(w1.dtype), dw3.astype(w3.dtype),
+            dw2.astype(w2.dtype), dgate, None, None, None, None)
+
+
+_held_experts.defvjp(_held_experts_fwd, _held_experts_bwd)
+
+
+#: assignments a block of the encode path's expert layer dispatches at once
+MOE_BLOCK = 512
+
+
+def _moe_dropless(p, x, cfg: TransformerConfig, route_mask):
+    """The encode path's expert layer: routes the tokens ``route_mask``
+    marks over all ``moe_num_experts`` and drops none.
+
+    Each marked token takes the softmax of its router logits (float32) and
+    its top ``moe_top_k``, gated by those probabilities (renormalised when
+    ``norm_topk_prob``).  The (token, expert) assignments that fall on the
+    held experts are sorted by expert and computed in blocks of
+    ``MOE_BLOCK``, as many blocks as there are assignments
+    (:func:`_held_experts`, differentiable by its own reverse rule): gather
+    the block's tokens, one grouped product per matrix over the held
+    experts' weights (:func:`_gmm`), gate, scatter-add into the output.  The
+    assignment buffers are sized for the most there can be, every token on
+    ``min(top_k, held)`` held experts, so nothing is dropped; padding gets
+    no assignment, so an embedding does not depend on its padding.  Plus
+    the shared experts, for every token.
+
+    x: (B, S, D); route_mask: (B, S) bool.  Returns (out, number of
+    assignments computed, int32).
+    """
+    B, S, D = x.shape
+    N, K, H = B * S, cfg.moe_top_k, cfg.experts_held
+    cd = cfg.compute_dtype
+    with jax.named_scope("moe"):
+        xf = x.reshape(N, D).astype(cd)
+        logits = jnp.matmul(xf.astype(jnp.float32),
+                            p["router"].astype(jnp.float32),
+                            precision=jax.lax.Precision.HIGHEST)
+        gates, idx = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), K)
+        if cfg.norm_topk_prob:
+            gates = gates / jnp.clip(gates.sum(-1, keepdims=True), 1e-9)
+        local = idx - cfg.moe_first_held
+        held = (local >= 0) & (local < H) & route_mask.reshape(N, 1)
+        key = jnp.where(held, local, H).reshape(N * K)
+        sizes = jax.ops.segment_sum(jnp.ones_like(key), key,
+                                    num_segments=H + 1)[:H]
+        n = jnp.sum(sizes)
+        starts = jnp.cumsum(sizes) - sizes
+        blk = min(MOE_BLOCK, -(-N * min(K, H) // 8) * 8)
+        bound = -(-N * min(K, H) // blk) * blk
+        order = jnp.argsort(key, stable=True)[:bound]
+        order = jnp.pad(order, (0, bound - order.shape[0]))
+        tok = order // K
+        gate = gates.reshape(N * K)[order]
+        w1, w3, w2 = (p[w].astype(cd) for w in ("w1", "w3", "w2"))
+        out = _held_experts(blk, xf, w1, w3, w2, gate, tok, starts, sizes,
+                            n)
+        out = out.astype(cd).reshape(B, S, D)
+        if cfg.moe_num_shared:
+            out = out + _dense_mlp(p["shared"], x, cfg)
+    return out, n
+
+
 # ---------------------------------------------------------------------------
 # Layer block + full forward
 # ---------------------------------------------------------------------------
 
 
 def _layer(p, x, cfg: TransformerConfig, *, positions, moe: bool, cache=None,
-           cache_index=None, kv_mask=None):
+           cache_index=None, kv_mask=None, route_mask=None):
     x = nn.constrain(x, ("act_batch", "act_seq", "act_embed"))
     attn_fn = _mla_attention if cfg.mla else _attention
     if cfg.norm_style == "post":
@@ -566,7 +797,10 @@ def _layer(p, x, cfg: TransformerConfig, *, positions, moe: bool, cache=None,
     h = _norm(p["mlp_norm"], x, cfg)
     aux = jnp.zeros((), jnp.float32)
     if moe:
-        mo, aux = _moe_block(p["moe"], h, cfg)
+        if route_mask is None:
+            mo, aux = _moe_block(p["moe"], h, cfg)
+        else:
+            mo, aux = _moe_dropless(p["moe"], h, cfg, route_mask)
         if cfg.moe_mode == "residual":
             mo = mo + _dense_mlp(p["mlp"], h, cfg)
     else:
@@ -575,14 +809,14 @@ def _layer(p, x, cfg: TransformerConfig, *, positions, moe: bool, cache=None,
 
 
 def _scan_stack(stack_params, x, cfg, *, moe, positions, caches=None,
-                cache_index=None, kv_mask=None):
+                cache_index=None, kv_mask=None, route_mask=None):
     """Scan a stacked layer group. caches: pytree stacked on leading L axis."""
     def body(carry, inp):
         h = carry
         lp, lc = inp
         h, new_cache, aux = _layer(lp, h, cfg, positions=positions, moe=moe,
                                    cache=lc, cache_index=cache_index,
-                                   kv_mask=kv_mask)
+                                   kv_mask=kv_mask, route_mask=route_mask)
         return h, (new_cache, aux)
 
     fn = jax.checkpoint(body) if cfg.remat else body
@@ -594,8 +828,13 @@ def _scan_stack(stack_params, x, cfg, *, moe, positions, caches=None,
 
 
 def forward(params, cfg: TransformerConfig, tokens, *, caches=None,
-            cache_index=None, kv_mask=None, positions=None):
-    """Run the trunk. Returns (hidden (B,S,D), new_caches, aux_loss)."""
+            cache_index=None, kv_mask=None, positions=None, route_mask=None):
+    """Run the trunk. Returns (hidden (B,S,D), new_caches, aux).
+
+    ``aux`` is the routers' load-balancing loss.  With ``route_mask``
+    ((B, S) bool; the encode path) the expert layers take the dropless form
+    (:func:`_moe_dropless`) over the tokens it marks, and ``aux`` is instead
+    the int32 number of (token, held expert) assignments they computed."""
     B, S = tokens.shape
     cd = cfg.compute_dtype
     x = nn.embedding(params["embed"], tokens, cd)
@@ -621,9 +860,10 @@ def forward(params, cfg: TransformerConfig, tokens, *, caches=None,
     if "moe_layers" in params:
         x, nc, aux = _scan_stack(params["moe_layers"], x, cfg, moe=True,
                                  positions=positions, caches=moe_caches,
-                                 cache_index=cache_index, kv_mask=kv_mask)
+                                 cache_index=cache_index, kv_mask=kv_mask,
+                                 route_mask=route_mask)
         new_caches["moe"] = nc
-        aux_total += aux
+        aux_total = aux if route_mask is not None else aux_total + aux
     if caches is not None:
         # preserve key parity with the input cache tree (a dense model's
         # init_cache carries "moe": None; dropping the key changes the
@@ -762,13 +1002,25 @@ def decode_step(params, cfg: TransformerConfig, caches, token, index):
 # ---------------------------------------------------------------------------
 
 
-def encode(params, cfg: TransformerConfig, tokens, mask, pooling: str = "mean"):
-    """Embed token sequences -> (B, D) L2-normalized vectors."""
-    hidden, _, _ = forward(params, cfg, tokens, kv_mask=mask)
+def encode(params, cfg: TransformerConfig, tokens, mask, pooling: str = "mean",
+           *, count_held: bool = False):
+    """Embed token sequences -> (B, D) L2-normalized vectors.
+
+    ``pooling``: ``cls`` (position 0), ``mean`` (over the mask) or ``last``
+    (the last marked position, ``sum(mask) - 1`` for right padding; 0 for a
+    row with none), the pooling of a causal trunk.  Expert layers take the
+    dropless form over the marked tokens; with ``count_held`` the result is
+    ``(embeddings, int32 number of (token, held expert) assignments)``."""
+    hidden, _, held = forward(params, cfg, tokens, kv_mask=mask,
+                              route_mask=mask)
     if pooling == "cls":
         emb = hidden[:, 0]
+    elif pooling == "last":
+        last = jnp.maximum(mask.sum(1) - 1, 0)
+        emb = jnp.take_along_axis(hidden, last[:, None, None], axis=1)[:, 0]
     else:
         m = mask.astype(hidden.dtype)[..., None]
         emb = (hidden * m).sum(1) / jnp.clip(m.sum(1), 1e-6)
     emb = emb.astype(jnp.float32)
-    return emb / jnp.clip(jnp.linalg.norm(emb, axis=-1, keepdims=True), 1e-6)
+    emb = emb / jnp.clip(jnp.linalg.norm(emb, axis=-1, keepdims=True), 1e-6)
+    return (emb, held) if count_held else emb

@@ -34,7 +34,8 @@ aggregates latencies for ``--obs_report`` without any span file I/O.
 While any ``Telemetry`` is alive, a ``gc.callbacks`` hook times every host
 garbage collection into its ``host.gc_pause_s`` histogram and a ``gc``
 span.  ``device_scopes`` maps each device instruction of the programs the
-engine dispatched to its named scope (``encode``/``score``/``merge``; see
+engine dispatched to its named scope (``encode``/``score``/``merge``, and
+``moe``/``experts`` inside an expert trunk's encode; see
 :mod:`repro.obs.scopes`), and ``device_programs`` names those programs: an
 instruction of another program (the query encoder) can have the same
 text.  ``mark``/``since`` provide cross-stage latency
@@ -58,10 +59,14 @@ from repro.obs.trace import LIFECYCLE_STAGES, SpanTracer, read_trace
 
 __all__ = ["Telemetry", "MetricsRegistry", "SpanTracer", "read_trace",
            "LIFECYCLE_STAGES", "Counter", "Gauge", "Ewma", "Histogram",
-           "SCOPES", "hlo_scopes", "live_telemetry", "GC_PAUSE_METRIC"]
+           "SCOPES", "hlo_scopes", "live_telemetry", "GC_PAUSE_METRIC",
+           "HELD_ASSIGNMENTS_METRIC"]
 
 #: histogram of host garbage-collection pauses, in seconds
 GC_PAUSE_METRIC = "host.gc_pause_s"
+#: histogram of the (token, held expert) assignments an expert trunk's
+#: passage stream computed, one observation per stream (a verdict's corpus)
+HELD_ASSIGNMENTS_METRIC = "moe.held_assignments"
 ANNOTATION_PREFIX = "repro."
 #: finished spans a Telemetry keeps in memory: a few hundred verdicts' worth
 RECENT_SPANS = 1 << 16

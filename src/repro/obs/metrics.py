@@ -140,6 +140,13 @@ class Histogram:
     def mean(self) -> Optional[float]:
         return (self.total / self.count) if self.count else None
 
+    def recent(self, n: int) -> List[float]:
+        """The newest ``n`` observations still in the reservoir, oldest
+        first."""
+        with self._lock:
+            vals = list(self._values)
+        return vals[max(len(vals) - n, 0):] if n > 0 else []
+
     def percentile(self, p: float) -> Optional[float]:
         """Nearest-rank percentile over the retained reservoir."""
         with self._lock:

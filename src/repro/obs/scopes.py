@@ -4,7 +4,9 @@ belongs to.
 The fused encode -> score -> top-k step names its parts with
 ``jax.named_scope``: ``encode`` (the passage encoder), ``score`` (query x
 passage scores and the validity mask) and ``merge`` (the running top-k
-fold).  The compiler keeps the scope in each instruction's
+fold).  Inside an expert trunk's encoder, ``moe`` is the expert layer
+(router, dispatch, held and shared experts, combine) and ``experts`` its
+grouped products alone; the innermost scope names an instruction.  The compiler keeps the scope in each instruction's
 ``metadata={op_name="jit(fused_window)/while/body/.../merge/..."}``, but a
 TPU profile names a device operation by its HLO text alone: operand types
 written out, no metadata, no backend configuration.  :func:`hlo_scopes`
@@ -30,7 +32,7 @@ __all__ = ["SCOPES", "hlo_scopes", "module_name", "op_head",
            "scope_of_op_name"]
 
 #: the fused step's layers, as ``jax.named_scope`` names them
-SCOPES: Tuple[str, ...] = ("encode", "score", "merge")
+SCOPES: Tuple[str, ...] = ("encode", "score", "merge", "moe", "experts")
 
 _MODULE = re.compile(r"^HloModule ([^\s,]+)", re.M)
 _NAME = re.compile(r"(?:ROOT\s+)?(%[^\s=]+) = ")

@@ -532,8 +532,9 @@ def test_engine_registers_each_program_once(tmp_path):
 def test_hlo_scopes_cover_the_fused_step():
     """Every non-parameter instruction of the CPU-compiled fused step
     (encode -> score -> top-k merge of one chunk), outside the scalar
-    reducers and comparators, lies in exactly one of the three scopes, and
-    each scope holds some."""
+    reducers and comparators, lies in exactly one of the scopes, and each
+    of the three a trunk without experts has (encode, score, merge) holds
+    some."""
     import re
 
     from repro.models import nn
@@ -577,4 +578,55 @@ def test_hlo_scopes_cover_the_fused_step():
     assert instrs
     assert all(scopes.get(_profile_text(i, types)) in SCOPES
                for i in instrs)
-    assert set(scopes.values()) == set(SCOPES)
+    assert set(scopes.values()) == {"encode", "score", "merge"}
+
+
+def _tiny_bert_fused_window_hlo(spec, encode_fn) -> str:
+    """The fused window (two chunks) of ``spec``'s trunk encoding passages
+    with ``encode_fn``, as HLO before optimisation, ``metadata`` stripped."""
+    import re
+
+    from repro.models import nn
+    params = nn.materialize(spec.init(jax.random.PRNGKey(0)))
+    stage = E.StreamTopKStage(encode_fn, k=3, query_ids=["q0", "q1"],
+                              doc_ids=[f"d{i}" for i in range(16)], window=2)
+    q = jnp.ones((2, 32))
+    run_s, run_i = stage.init(q)
+    lowered = stage._fused_window.lower(
+        params, q, run_s, run_i, jnp.ones((2, 8, 16), jnp.int32),
+        jnp.ones((2, 8, 16), bool), jnp.zeros(2, jnp.int32),
+        jnp.full(2, 8, jnp.int32))
+    return re.sub(r",? metadata=\{[^}]*\}", "",
+                  lowered.compiler_ir("hlo").as_hlo_text())
+
+
+@pytest.mark.parametrize("pooling", ["cls", "mean"])
+def test_fused_step_of_a_trunk_without_experts_is_unchanged(pooling):
+    """A trunk without expert layers offers no counted encoder, so its
+    stage keeps the two-array carry, and its fused window holds the same
+    instructions as one whose encoder calls the trunk as it was called
+    before the encode path had a route mask (``forward`` with the key mask
+    alone, pooled and normalised the same way)."""
+    from repro.models import transformer as tfm
+    from repro.models.biencoder import biencoder_spec
+    cfg = tfm.TransformerConfig(name="tiny", n_layers=1, d_model=32,
+                                n_heads=2, n_kv_heads=2, head_dim=16,
+                                d_ff=64, vocab_size=97, use_rope=False,
+                                causal=False, max_position_embeddings=16,
+                                norm_style="post", act="gelu", qkv_bias=True)
+    spec = biencoder_spec(cfg, pooling=pooling, q_max_len=8, p_max_len=16)
+    assert spec.encode_passage_counted is None
+
+    def unrouted(params, tokens, mask):
+        hidden, _, _ = tfm.forward(params, cfg, tokens, kv_mask=mask)
+        if pooling == "cls":
+            emb = hidden[:, 0]
+        else:
+            m = mask.astype(hidden.dtype)[..., None]
+            emb = (hidden * m).sum(1) / jnp.clip(m.sum(1), 1e-6)
+        emb = emb.astype(jnp.float32)
+        return emb / jnp.clip(jnp.linalg.norm(emb, axis=-1, keepdims=True),
+                              1e-6)
+
+    got = _tiny_bert_fused_window_hlo(spec, spec.encode_passage)
+    assert got == _tiny_bert_fused_window_hlo(spec, unrouted)

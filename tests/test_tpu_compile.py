@@ -117,3 +117,53 @@ def test_topk_mips_compiles(one_chip, score_dtype, k):
         q, c, k=k, interpret=False, score_dtype=score_dtype))
     compiled = f.lower(q, c).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_dropless_expert_layer_compiles(one_chip, monkeypatch):
+    """The encode path's expert layer at DeepSeek-V2-Lite's widths and the
+    cell's chunk (64 passages x 128 tokens): router over 64 experts, top 6,
+    8 held, each block's three grouped products as Pallas kernels."""
+    import dataclasses
+
+    from repro.models import transformer as tfm
+    monkeypatch.setattr(tfm, "_gmm_interpret", lambda: False)
+    cfg = dataclasses.replace(
+        registry.get("deepseek-v2-lite-16b").full_config(),
+        moe_experts_held=8)
+    shapes = jax.eval_shape(lambda: nn.materialize(
+        tfm._moe_init(jax.random.PRNGKey(0), cfg)))
+    params = jax.tree_util.tree_map(
+        lambda x: _arg(x.shape, x.dtype, one_chip), shapes)
+    x = _arg((64, 128, cfg.d_model), jnp.bfloat16, one_chip)
+    mask = _arg((64, 128), jnp.bool_, one_chip)
+    f = jax.jit(lambda p, x, m: tfm._moe_dropless(p, x, cfg, m))
+    text = f.lower(params, x, mask).compile().as_text()
+    assert text.count("tpu_custom_call") == 3
+
+
+def test_dropless_expert_layer_gradient_compiles(one_chip, monkeypatch):
+    """The same layer's reverse rule compiles for the chip at those widths:
+    the forward's three grouped products, their recomputation, the three
+    transposed products of the input's cotangent and the three per-expert
+    weight products (``tgmm``), all Pallas kernels."""
+    import dataclasses
+
+    from repro.models import transformer as tfm
+    monkeypatch.setattr(tfm, "_gmm_interpret", lambda: False)
+    cfg = dataclasses.replace(
+        registry.get("deepseek-v2-lite-16b").full_config(),
+        moe_experts_held=8)
+    shapes = jax.eval_shape(lambda: nn.materialize(
+        tfm._moe_init(jax.random.PRNGKey(0), cfg)))
+    params = jax.tree_util.tree_map(
+        lambda x: _arg(x.shape, x.dtype, one_chip), shapes)
+    x = _arg((8, 128, cfg.d_model), jnp.bfloat16, one_chip)
+    mask = _arg((8, 128), jnp.bool_, one_chip)
+
+    def loss(p, x, m):
+        out, _ = tfm._moe_dropless(p, x, cfg, m)
+        return jnp.sum(out.astype(jnp.float32) ** 2)
+
+    f = jax.jit(jax.grad(loss, argnums=(0, 1)))
+    text = f.lower(params, x, mask).compile().as_text()
+    assert text.count("tpu_custom_call") == 12
