@@ -51,8 +51,8 @@ import hashlib
 import json
 import os
 import time
-from typing import (Any, Callable, Dict, Iterator, List, Optional, Sequence,
-                    Tuple)
+from typing import (Any, Callable, Dict, Iterator, List, Mapping, Optional,
+                    Sequence, Tuple)
 
 import jax
 import jax.numpy as jnp
@@ -60,6 +60,7 @@ import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from repro.core.encoder import cached_compiled, encode_texts, jitted_encoder
+from repro.core.metrics import RankedRun, RankedScores, RunIds
 from repro.core.precision import chunk_scores, validate_score_dtype
 from repro.core.registry import (ENGINES, IMPLS, MODES, STAGES,
                                  register_engine, register_impl,
@@ -71,8 +72,8 @@ from repro.core.retrieval import (_hierarchical_slot_max,
 from repro.data.corpus import Tokens, pad_batch
 from repro.obs import HELD_ASSIGNMENTS_METRIC
 
-Run = Dict[str, List[str]]
-Scores = Dict[str, List[float]]
+Run = Mapping[str, List[str]]
+Scores = Mapping[str, List[float]]
 
 
 def _donate(*argnums: int) -> tuple:
@@ -593,6 +594,7 @@ class StreamTopKStage(Stage):
                  encode_counted: Optional[Callable] = None):
         self.query_ids = query_ids
         self.doc_ids = doc_ids
+        self.run_ids = RunIds(query_ids, doc_ids)
         self.k = max(1, min(k, len(doc_ids))) if doc_ids else 0
         self.window = max(1, window)
         self.score_dtype = validate_score_dtype(score_dtype)
@@ -673,11 +675,10 @@ class StreamTopKStage(Stage):
         if self.counted and tel is not None:
             tel.metrics.histogram(HELD_ASSIGNMENTS_METRIC).observe(
                 int(carry[2]))
-        run, scores = {}, {}
-        for qi, qid in enumerate(self.query_ids):
-            run[qid] = [self.doc_ids[j] for j in run_i[qi]]
-            scores[qid] = [float(s) for s in run_s[qi]]
-        return run, scores
+        # the (Q, k) arrays read as run and score dicts: a query's lists
+        # are built only when a consumer reads them
+        return RankedRun(run_i, self.run_ids), RankedScores(run_s,
+                                                            self.run_ids)
 
 
 class PallasStreamTopKStage(StreamTopKStage):
@@ -1226,7 +1227,8 @@ class StreamingEngine:
         # key names kept from the legacy path: the ledger/CSV schema is
         # stable across engines.  encode_corpus_s is the fused loop (encode
         # AND fold — they are one program now); retrieve_s is the host-side
-        # finalize only: the carry's copy to the host and the run lists.
+        # finalize only: the carry's copy to the host and the stage's
+        # finalize (run views for the top-k stages, lists for rerank).
         timings = {"encode_corpus_s": t_stream, "encode_query_s": t_query,
                    "retrieve_s": t_final,
                    "total_s": t_query + t_stream + t_final}

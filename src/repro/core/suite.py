@@ -427,7 +427,8 @@ class ValidationSuite:
             m = metrics_lib.compute_metrics(run, task.qrels, names)
         else:
             with tel.span("metrics", step=step, task=name):
-                m = metrics_lib.compute_metrics(run, task.qrels, names)
+                m = metrics_lib.compute_metrics(run, task.qrels, names,
+                                                telemetry=tel)
         v = self.vcfg
         do_write = v.write_run if write_runs is None else write_runs
         if do_write and v.output_dir:

@@ -19,12 +19,15 @@ hand-off order::
     staged     -> the wait for one staged token batch (one span per batch)
     dispatch   -> one fused encode -> score -> top-k step dispatched
     drain      -> the wait for the last dispatched step's carry
-    finalize   -> the carry to run lists, with two children:
+    finalize   -> the carry to a run, with two children:
                   finalize.fetch (device -> host copy of the carry) and
-                  finalize.build (the run lists, on the host copy)
+                  finalize.build (the run on the host copy: array views
+                  for the top-k stages, lists for the rerank stages)
     scored     -> one full engine run for one (step, task) unit (span);
                   the engine's spans above, from placed on, nest under it
-    metrics    -> the verdict's metrics computed from the run lists (span)
+    metrics    -> the verdict's metrics, from an array run's rows or else
+                  from the run's lists (span; counters metrics.array_path,
+                  metrics.list_path)
     recorded   -> ledger append of the verdict rows (span)
     selected   -> control plane changed its best-step choice (event)
     promoted   -> serving promoter built+verified+installed an index (span)

@@ -137,6 +137,59 @@ def test_stream_topk_window_bitwise_vs_topk_exact(N, chunk, k, window):
     np.testing.assert_array_equal(np.asarray(carry[1]), np.asarray(ei))
 
 
+def test_stream_topk_finalize_reads_the_carry_as_the_old_lists(tmp_path):
+    """finalize hands back views of the (Q, k) carry: equal, as mappings,
+    to the run and score dicts it once built query by query, in the same
+    query order, and the TREC file written from either is the same."""
+    from repro.core import metrics as M
+    params, doc_texts, _, q_emb = _gather_setup(60, Q=6)
+    store = E.TokenStore.build(doc_texts, max_len=2, chunk=16)
+    stage = E.StreamTopKStage(_gather_encode, k=10,
+                              query_ids=[f"q{i}" for i in range(6)],
+                              doc_ids=[f"d{i}" for i in range(60)])
+    carry = stage.init(q_emb)
+    for toks, mask, base, n_valid in store.chunks():
+        carry = stage.step(params, q_emb, carry, toks, mask, base, n_valid)
+    carry = jax.device_get(carry)
+    run, scores = stage.finalize(carry)
+    assert isinstance(run, M.RankedRun) and isinstance(scores,
+                                                       M.RankedScores)
+    old_run, old_scores = {}, {}
+    for qi, qid in enumerate(stage.query_ids):
+        old_run[qid] = [stage.doc_ids[j] for j in carry[1][qi]]
+        old_scores[qid] = [float(s) for s in carry[0][qi]]
+    assert run == old_run and scores == old_scores
+    assert list(run.items()) == list(old_run.items())
+    assert list(scores.items()) == list(old_scores.items())
+    view, plain = tmp_path / "view.trec", tmp_path / "plain.trec"
+    M.write_trec_run(str(view), run, scores, tag="t")
+    M.write_trec_run(str(plain), dict(run), dict(scores), tag="t")
+    assert view.read_bytes() == plain.read_bytes()
+    assert len(view.read_bytes().splitlines()) == 60
+
+
+@pytest.mark.parametrize("engine,path", [("streaming", "array_path"),
+                                         ("materialized", "list_path")])
+def test_suite_counts_the_metrics_path_once_per_verdict(ds, engine, path):
+    """The streaming engine's verdicts are scored from the carry's rows, a
+    plain run dict from its lists: one count a verdict, on one path."""
+    from repro.core.suite import ValidationSuite, ValidationTask
+    from repro.obs import Telemetry
+    tel = Telemetry(None)
+    spec = _toy_spec()
+    vcfg = ValidationConfig(metrics=("MRR@10", "Recall@100"), k=100,
+                            batch_size=64, chunk_size=64, engine=engine,
+                            telemetry=tel)
+    suite = ValidationSuite(spec, [ValidationTask(
+        "default", ds.corpus, ds.queries, ds.qrels)], vcfg)
+    params = spec.init(jax.random.PRNGKey(1))
+    for step in (1, 2):
+        suite.validate_params(params, step=step)
+    other = {"array_path": "list_path", "list_path": "array_path"}[path]
+    assert tel.metrics.get(f"metrics.{path}").value == 2
+    assert tel.metrics.get(f"metrics.{other}") is None
+
+
 def test_stream_pallas_matches_xla_stream():
     params, doc_texts, c_emb, q_emb = _gather_setup(45, Q=4)
     xs, xi = _stream_topk(E.StreamTopKStage, params, q_emb, doc_texts,

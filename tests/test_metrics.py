@@ -87,6 +87,133 @@ def test_mrr_bounded_and_monotone_in_k(ranks):
 
 
 # ---------------------------------------------------------------------------
+# runs held as (Q, k) row arrays
+# ---------------------------------------------------------------------------
+
+ALL_METRICS = ["MRR@1", "MRR@10", "Recall@1", "Recall@5", "Recall@8",
+               "Recall@100", "Success@1", "Success@8", "Success@50",
+               "nDCG@3", "nDCG@8", "nDCG@20", "AverageRank"]
+
+
+def _tel():
+    from types import SimpleNamespace
+
+    from repro.obs.metrics import MetricsRegistry
+    return SimpleNamespace(metrics=MetricsRegistry())
+
+
+def _counts(tel):
+    out = {}
+    for path in ("array_path", "list_path"):
+        counter = tel.metrics.get(f"metrics.{path}")
+        out[path] = 0 if counter is None else counter.value
+    return out
+
+
+def _ranked_case(seed, *, unique_docs=True, repeat_rows=False):
+    """A run view of 40 queries at depth 8 over 60 passages, and qrels with
+    queries absent from the run and from the qrels, several relevant
+    passages a query (every result judged for some), relevant ids outside
+    the corpus, zero and negative gains."""
+    rng = np.random.default_rng(seed)
+    Q, k, N = 40, 8, 60
+    qids = [f"q{i}" for i in range(Q)]
+    dids = [f"d{i % 45}" if not unique_docs else f"d{i}" for i in range(N)]
+    rows = np.stack([rng.choice(N, size=k, replace=repeat_rows)
+                     for _ in range(Q)]).astype(np.int32)
+    qrels = {}
+    for i, q in enumerate(qids):
+        if i % 7 == 3:
+            continue                               # absent from the qrels
+        judged = {}
+        for d in rng.choice(N, size=int(rng.integers(1, 5)), replace=False):
+            judged[dids[d]] = int(rng.choice([-1, 0, 1, 1, 2, 3]))
+        if rng.random() < 0.5:                     # one of its own results
+            judged[dids[rows[i, rng.integers(k)]]] = int(rng.integers(1, 4))
+        if i % 5 == 1:                             # judged all through
+            for j in rows[i]:
+                judged[dids[j]] = int(rng.integers(-1, 4))
+        if rng.random() < 0.3:
+            judged[f"outside{i}"] = 1              # not in the corpus
+        qrels[q] = judged
+    qrels["q_not_run"] = {"d0": 1}
+    ids = M.RunIds(qids, dids)
+    return M.RankedRun(rows, ids), qrels
+
+
+@pytest.mark.parametrize("seed", range(8))
+def test_ranked_run_metrics_equal_the_list_path_bitwise(seed):
+    run, qrels = _ranked_case(seed, repeat_rows=seed % 4 == 3)
+    tel = _tel()
+    got = M.compute_metrics(run, qrels, ALL_METRICS, telemetry=tel)
+    want = M.compute_metrics(dict(run), qrels, ALL_METRICS, telemetry=tel)
+    assert got == want
+    assert _counts(tel) == {"array_path": 1, "list_path": 1}
+
+
+def test_ranked_run_over_repeated_doc_ids_takes_the_list_path():
+    run, qrels = _ranked_case(5, unique_docs=False)
+    assert run.ids.doc_row is None
+    tel = _tel()
+    got = M.compute_metrics(run, qrels, ALL_METRICS, telemetry=tel)
+    assert got == M.compute_metrics(dict(run), qrels, ALL_METRICS)
+    assert _counts(tel) == {"array_path": 0, "list_path": 1}
+
+
+def test_ranked_run_reads_as_the_dict_of_its_rows():
+    ids = M.RunIds(["q0", "q1", "q0"], ["a", "b", "c"])
+    rows = np.asarray([[2, 0], [1, 2], [0, 1]], np.int32)
+    scores = np.asarray([[0.5, 0.25], [0.1, -1.0], [0.3, 0.2]], np.float32)
+    run, sc = M.RankedRun(rows, ids), M.RankedScores(scores, ids)
+    plain_run, plain_sc = {}, {}                  # filled query by query
+    for qi, qid in enumerate(ids.query_ids):
+        plain_run[qid] = [ids.doc_ids[j] for j in rows[qi]]
+        plain_sc[qid] = [float(s) for s in scores[qi]]
+    assert run == plain_run and plain_run == run
+    assert sc == plain_sc and list(sc) == list(plain_sc) == ["q0", "q1"]
+    assert len(run) == 2 and run["q0"] == ["a", "b"]
+    assert run.get("zz") is None and "q1" in run and "zz" not in run
+    assert list(run.items()) == list(plain_run.items())
+    assert all(type(v) is float for v in sc["q1"])
+    with pytest.raises(KeyError):
+        run["zz"]
+    assert M.compute_metrics(run, {"q0": {"a": 1}}, ["MRR@2"]) == \
+        M.compute_metrics(plain_run, {"q0": {"a": 1}}, ["MRR@2"])
+
+
+def test_ranked_run_once_read_takes_the_list_path():
+    run, qrels = _ranked_case(1)
+    first = next(iter(run))
+    run[first].reverse()                 # a consumer's edit, as on a dict
+    assert run[first] is run[first]
+    tel = _tel()
+    got = M.compute_metrics(run, qrels, ALL_METRICS, telemetry=tel)
+    assert got == M.compute_metrics(dict(run), qrels, ALL_METRICS)
+    assert _counts(tel) == {"array_path": 0, "list_path": 1}
+
+
+def test_ranked_run_with_rows_outside_the_corpus_takes_the_list_path():
+    ids = M.RunIds(["q0"], ["a", "b", "c"])
+    run = M.RankedRun(np.asarray([[-1, 0]], np.int32), ids)
+    assert not run.rows_index_corpus()
+    tel = _tel()
+    # a row of -1 names the last passage, as indexing the id list does
+    assert M.compute_metrics(run, {"q0": {"c": 1}}, ["MRR@10"],
+                             telemetry=tel) == {"MRR@10": 1.0}
+    assert _counts(tel) == {"array_path": 0, "list_path": 1}
+
+
+def test_ranked_run_relevance_index_is_built_once_per_qrels():
+    run, qrels = _ranked_case(2)
+    M.compute_metrics(run, qrels, ["MRR@10"])
+    index = run.ids.relevance(qrels)
+    M.compute_metrics(M.RankedRun(run.array[::-1].copy(), run.ids), qrels,
+                      ["MRR@10"])
+    assert run.ids.relevance(qrels) is index
+    assert run.ids.relevance(dict(qrels)) is not index
+
+
+# ---------------------------------------------------------------------------
 # fidelity statistics
 # ---------------------------------------------------------------------------
 
