@@ -19,6 +19,7 @@ import time
 
 from benchmarks.common import Timer, toy_spec, train_toy_dr
 from repro.ckpt import checkpoint as ckpt
+from repro.core.suite import ValidationConfig
 from repro.data import corpus as corpus_lib
 from repro.serve import (AdmissionController, IndexBuilder, Promoter,
                          QueryService, ServeConfig, replay_swaps)
@@ -43,8 +44,8 @@ def run(n_passages: int = 600, n_queries: int = 24, n_clients: int = 4,
             ckpt.save(ckdir, step, {"params": params})
         steps = [s for s, _ in snaps]
 
-        builder = IndexBuilder(spec, ds.corpus,
-                               ServeConfig(k=10, batch_size=64))
+        builder = IndexBuilder(spec, ds.corpus, ServeConfig(
+            scoring=ValidationConfig(k=10, batch_size=64)))
         admission = AdmissionController(max_pending=256)
         service = QueryService(spec, k=10, max_batch=8, flush_ms=2.0,
                                admission=admission)

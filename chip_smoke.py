@@ -470,10 +470,12 @@ def phase_reference(s: Setup):
 
 
 def phase_serve(s: Setup, ref: Reference, params):
+    from repro.core.suite import ValidationConfig
     from repro.serve import IndexBuilder, QueryService, ServeConfig
 
     z = s.sizes
-    cfg = ServeConfig(k=z.serve_k, batch_size=z.batch)
+    cfg = ServeConfig(scoring=ValidationConfig(k=z.serve_k,
+                                               batch_size=z.batch))
     index = IndexBuilder(s.spec, s.ds.corpus, cfg).build(params, ref.step)
     service = QueryService(s.spec, k=z.serve_k, max_batch=cfg.max_batch)
     service.install(index)

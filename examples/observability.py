@@ -157,7 +157,8 @@ def main():
                            admission=AdmissionController(64),
                            telemetry=tel_main)
     promoter = Promoter(
-        IndexBuilder(spec, ds.corpus, ServeConfig(k=10, batch_size=32)),
+        IndexBuilder(spec, ds.corpus, ServeConfig(
+            scoring=ValidationConfig(k=10, batch_size=32))),
         service, ckdir,
         target_fn=lambda: control.selector.best_step,
         log=os.path.join(workdir, "serve.jsonl"), telemetry=tel_main)

@@ -66,8 +66,7 @@ def main():
         event_path=os.path.join(workdir, "control.jsonl"))
 
     # -- serving tier: same spec, same corpus, same scoring knobs ----------
-    builder = IndexBuilder(spec, ds.corpus,
-                           ServeConfig(k=10, batch_size=64))
+    builder = IndexBuilder(spec, ds.corpus, ServeConfig(scoring=suite.vcfg))
     service = QueryService(spec, k=10, max_batch=8, flush_ms=2.0,
                            admission=AdmissionController(max_pending=256))
     promoter = Promoter(builder, service, ckdir,

@@ -545,18 +545,14 @@ def main(argv=None) -> int:
     if args.serve:
         from repro.serve import (AdmissionController, IndexBuilder,
                                  Promoter, QueryService, ServeConfig)
-        # the serving tier reuses the validator's exact scoring knobs —
-        # same score_dtype, same impl, same token-store geometry — so the
-        # answers it hands out are bitwise the numbers the ledger records
-        scfg = ServeConfig(k=args.serve_k, score_dtype=args.score_dtype,
-                           impl=args.impl, batch_size=args.batch_size,
-                           chunk_size=args.chunk_size,
-                           max_batch=args.serve_batch,
+        # the serving index scores with the validator's own config — same
+        # score_dtype, impl, mesh and token-store geometry; only the
+        # cut-off is the serving one — so the answers it hands out are
+        # bitwise the numbers the ledger records
+        scfg = ServeConfig(max_batch=args.serve_batch,
                            flush_ms=args.serve_flush_ms,
                            max_pending=args.serve_pending,
-                           token_backing=args.token_backing,
-                           mmap_dir=mmap_dir,
-                           token_fingerprint=args.token_fingerprint)
+                           scoring=dataclasses.replace(vcfg, k=args.serve_k))
         serve_service = QueryService(
             spec, k=args.serve_k, max_batch=args.serve_batch,
             flush_ms=args.serve_flush_ms,

@@ -454,7 +454,7 @@ def encode_store(encode_fn: Callable, params, store: TokenStore, *,
     shard encodes its rows under one ``shard_map`` — the same sharded stage
     the corpus streams through, so huge query sets scale with the mesh
     instead of capping on one device.  Requires ``store.chunk`` divisible by
-    the shard count (``make_engine`` rounds the query chunk up to that).
+    the shard count (:func:`chunk_geometry` gives each shard whole rows).
     """
     if mesh is None:
         fn = jitted_encoder(encode_fn)
@@ -1345,20 +1345,24 @@ class ValidationStore:
 
 def chunk_geometry(vcfg, n_docs: int, mesh=None) -> Tuple[int, int]:
     """(corpus chunk rows, query chunk rows) for a config.  ``chunk_size``
-    defaults to ``batch_size`` (legacy-equivalent encode granularity); with
-    a mesh both are rounded up to a multiple of the shard count so every
-    shard sees equal fixed-shape rows — for EVERY mode: retrieval, rerank,
-    and average_rank all shard through the same ``make_stage`` dispatch.
-    Shared by the engine factories and the suite's TokenStore cache (two
-    tasks share a store only when this geometry matches)."""
-    chunk = vcfg.chunk_size or vcfg.batch_size
-    chunk = max(1, min(chunk, max(n_docs, 1)))
+    defaults to ``batch_size`` (legacy-equivalent encode granularity).  A
+    chunk keeps its configured width whatever the corpus size: rows past
+    the corpus are masked by ``n_valid``, so every consumer of one config
+    (validator, serving index, ``topk_exact`` at ``block=chunk``) scores
+    the same ``(Q, chunk)`` blocks.  With a mesh both widths are per
+    shard: a dispatch holds ``chunk`` rows on each shard, so a shard scores
+    exactly the block a single device does — for EVERY mode: retrieval,
+    rerank, and average_rank all shard through the same ``make_stage``
+    dispatch.  Shared by the engine factories, the serving index and the
+    suite's TokenStore cache (two tasks share a store only when this
+    geometry matches).  ``n_docs`` no longer shapes it."""
+    del n_docs
+    chunk = max(1, vcfg.chunk_size or vcfg.batch_size)
     q_chunk = max(1, vcfg.batch_size)
     if mesh is not None:
         n_shards = int(np.prod([mesh.shape[a] for a in mesh.axis_names]))
-        chunk = -(-chunk // n_shards) * n_shards
-        # query chunks shard over the same mesh: equal fixed-shape rows too
-        q_chunk = -(-q_chunk // n_shards) * n_shards
+        chunk *= n_shards
+        q_chunk *= n_shards
     return chunk, q_chunk
 
 

@@ -75,11 +75,13 @@ def topk_exact(q_emb: jnp.ndarray, c_emb: jnp.ndarray, *, k: int,
     picks the scoring precision via :func:`repro.core.precision.
     chunk_scores`; ``"f32"`` compiles the literal legacy expression.
     Per-row quantization makes the block scores block-size independent, so
-    every precision agrees with the streaming stages at equal dtype."""
+    every precision agrees with the streaming stages at equal dtype.  A
+    block keeps its width even past ``N`` (the rows beyond are masked), as
+    a TokenStore chunk does, so ``block=chunk`` scores the stream's blocks."""
     Q, D = q_emb.shape
     N = c_emb.shape[0]
     k = min(k, N)
-    nb = max(1, min(block, N))
+    nb = max(1, block)
     n_blocks = -(-N // nb)
     padN = n_blocks * nb
     c = jnp.pad(c_emb, ((0, padN - N), (0, 0)))

@@ -87,6 +87,7 @@ def main(argv=None) -> int:
     args = ap.parse_args(argv)
 
     from repro.core.cli import build_encoder, load_texts
+    from repro.core.suite import ValidationConfig
     from repro.serve import (AdmissionController, IndexBuilder, Promoter,
                              QueryService, ServeConfig)
 
@@ -97,12 +98,13 @@ def main(argv=None) -> int:
     print(f"[serve] corpus={len(corpus)} queries={len(queries)}",
           file=sys.stderr)
 
-    cfg = ServeConfig(k=args.k, score_dtype=args.score_dtype,
-                      impl=args.impl, batch_size=args.batch_size,
-                      max_batch=args.max_batch, flush_ms=args.flush_ms,
-                      max_pending=args.max_pending)
+    cfg = ServeConfig(max_batch=args.max_batch, flush_ms=args.flush_ms,
+                      max_pending=args.max_pending,
+                      scoring=ValidationConfig(
+                          k=args.k, score_dtype=args.score_dtype,
+                          impl=args.impl, batch_size=args.batch_size))
     builder = IndexBuilder(spec, corpus, cfg)
-    service = QueryService(spec, k=cfg.k, max_batch=cfg.max_batch,
+    service = QueryService(spec, k=args.k, max_batch=cfg.max_batch,
                            flush_ms=cfg.flush_ms,
                            admission=AdmissionController(cfg.max_pending))
     promoter = Promoter(
@@ -116,7 +118,8 @@ def main(argv=None) -> int:
         print("[serve] no committed checkpoint to promote", file=sys.stderr)
         return 1
     print(f"[serve] live step {service.live_step()} "
-          f"({builder.store.n_texts} docs, score_dtype={cfg.score_dtype})",
+          f"({builder.store.n_texts} docs, "
+          f"score_dtype={cfg.scoring.score_dtype})",
           file=sys.stderr)
 
     responses = service.answer(list(queries.items()))

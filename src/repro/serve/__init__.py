@@ -6,14 +6,15 @@ the scoring math.  Three planes:
 
   * index   — :class:`~repro.serve.index.IndexBuilder` encodes the corpus
     once per promoted checkpoint through the SAME ``TokenStore`` /
-    ``encode_store`` machinery the validator streams through, into a
-    device-resident (optionally sharded, optionally ``score_dtype``-
-    quantized) :class:`~repro.serve.index.ServingIndex`.
+    ``encode_store`` machinery the validator streams through, in the
+    validator's chunk geometry, into a device-resident, chunk-major
+    :class:`~repro.serve.index.ServingIndex`.
   * request — :class:`~repro.serve.service.QueryService` micro-batches
     queries (max-latency flush), encodes them with the same cached
-    encoder, and scores through the same ``topk_exact`` / ``topk_sharded``
-    / pallas ``topk_mips`` dispatch the validator uses — so serving
-    numbers ARE validation numbers, bit for bit (Kim et al. 2022's
+    encoder, and folds the resident chunks through the validator's own
+    retrieval stage (``make_stage``: the fused score, mask and top-k merge,
+    rows past a chunk's end masked as the validator masks them) — so
+    serving numbers ARE validation numbers, bit for bit (Kim et al. 2022's
     training-inference gap, closed by construction and locked by
     tests/test_serve_parity.py).
   * promotion — :class:`~repro.serve.promoter.Promoter` tails the control

@@ -8,8 +8,9 @@ tails them and swaps the live serving index in two phases mirroring
   1. build   — restore the checkpoint, encode the corpus into a fresh
      :class:`~repro.serve.index.ServingIndex` OFF to the side (queries
      keep answering on the old index the whole time);
-  2. verify + flip — probe the candidate (shape/finiteness/canary
-     search, which also pre-warms the compiled search program), then
+  2. verify + flip — probe the candidate (finiteness and a canary
+     search in the service's fixed query shape, which also pre-warms the
+     compiled search program), then
      atomically flip the service's live pointer.  A failure anywhere
      leaves the old index serving and is recorded as ``swap_failed``.
 
@@ -120,20 +121,18 @@ class Promoter:
     # -- two-phase swap -----------------------------------------------------
     def verify(self, index: ServingIndex) -> None:
         """Phase-two gate, BEFORE the flip: structural checks plus a
-        canary search that also pre-warms the compiled search program so
-        the first real post-swap batch never pays a compile."""
+        canary search in the service's fixed query shape, which also
+        pre-warms the one compiled search program the service runs, so the
+        first real post-swap batch never pays a compile."""
         if index.n_docs < 1:
             raise ValueError("candidate index is empty")
-        if index.n_docs != len(index.doc_ids):
-            raise ValueError(
-                f"candidate index rows ({index.n_docs}) != doc ids "
-                f"({len(index.doc_ids)})")
-        emb32 = jnp.asarray(index.emb, jnp.float32)
-        if not bool(jnp.all(jnp.isfinite(emb32))):
+        embs = [emb for emb, _, _ in index.folds]
+        if not all(bool(jnp.all(jnp.isfinite(jnp.asarray(e, jnp.float32))))
+                   for e in embs):
             raise ValueError("candidate index has non-finite embeddings")
-        canary = np.zeros((1, int(index.emb.shape[1])), np.float32)
-        ids, _ = index.search(canary,
-                              k=min(self.service.k, index.n_docs))
+        canary = np.zeros((self.service.q_rows, int(embs[0].shape[-1])),
+                          np.float32)
+        ids, _ = index.search(canary, k=self.service.k)
         if not ids or not ids[0]:
             raise ValueError("candidate index answered an empty canary")
 
@@ -162,8 +161,9 @@ class Promoter:
             self.log.emit("swap", want,
                           prev_step=prev if prev is not None else -1,
                           engine="serve",
-                          score_dtype=index.score_dtype,
-                          impl=index.impl, n_docs=index.n_docs,
+                          score_dtype=self.builder.cfg.scoring.score_dtype,
+                          impl=self.builder.cfg.scoring.impl,
+                          n_docs=index.n_docs,
                           build_s=round(index.build_s, 6))
             self.swaps.append((prev, want))
             if tel is not None:
@@ -179,7 +179,7 @@ class Promoter:
             self.log.emit("swap_failed", want,
                           error=f"{type(e).__name__}: {e}",
                           engine="serve",
-                          score_dtype=self.builder.cfg.score_dtype,
+                          score_dtype=self.builder.cfg.scoring.score_dtype,
                           live_step=live if live is not None else -1)
             return False
         finally:

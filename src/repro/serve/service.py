@@ -3,17 +3,22 @@
 Requests are collected into micro-batches — a batch dispatches when it
 reaches ``max_batch`` or when the oldest request has waited ``flush_ms``
 (the classic throughput/latency trade) — padded to ONE fixed
-``(max_batch, q_max_len)`` shape so the whole serving life runs a single
-compiled encode program, and scored through the live
-:class:`~repro.serve.index.ServingIndex`.
+``(q_rows, q_max_len)`` shape, encoded, and scored through the live
+:class:`~repro.serve.index.ServingIndex` as that whole fixed block; the
+pad rows are dropped after the top-k.  So the whole serving life runs one
+compiled encode program and one compiled search program.
 
 Two properties the tests lean on:
 
   * bit parity — queries are encoded by the same cached
     :func:`~repro.core.encoder.jitted_encoder` the validator uses, and
-    encoders are row-independent, so a query's embedding (hence its
-    scores, hence its ranking) is identical whether it arrives alone,
-    in a full micro-batch, or inside the validator's big encode chunks.
+    encoders are row-independent, so a query's embedding is identical
+    whether it arrives alone, in a full micro-batch, or inside the
+    validator's big encode chunks.  Its scores are too: the block the
+    index scores always has ``q_rows`` rows, ``max_batch`` rounded up to
+    whole 8-row tiles (the TPU's float32 sublanes), so a lone query never
+    takes the 1-row matrix-vector path whose last bits differ from the
+    validator's many-query block.
   * exactly-one-step attribution — the live-index pointer is read ONCE
     per micro-batch and every response in the batch carries that index's
     checkpoint step; a concurrent hot-swap flips the pointer between
@@ -35,6 +40,8 @@ from repro.core.encoder import jitted_encoder
 from repro.data.corpus import pad_batch
 from repro.serve.admission import AdmissionController, ServeOverloaded
 from repro.serve.index import ServingIndex
+
+_TILE_ROWS = 8
 
 
 @dataclasses.dataclass
@@ -72,6 +79,8 @@ class QueryService:
         self.spec = spec
         self.k = int(k)
         self.max_batch = int(max_batch)
+        # rows of every block the index scores (see the module docstring)
+        self.q_rows = -(-self.max_batch // _TILE_ROWS) * _TILE_ROWS
         self.flush_s = float(flush_ms) / 1000.0
         self.admission = admission
         # observation only: `served` spans per micro-batch plus occupancy /
@@ -228,14 +237,10 @@ class QueryService:
     def _score(self, index: ServingIndex, token_rows):
         B = len(token_rows)
         toks, mask = pad_batch(list(token_rows), self.spec.q_max_len)
-        if B < self.max_batch:
-            # fixed (max_batch, L) shape: one compiled program for every
-            # batch size; pad rows are discarded below (row independence)
-            pad = self.max_batch - B
-            toks = np.concatenate(
-                [toks, np.zeros((pad, toks.shape[1]), toks.dtype)])
-            mask = np.concatenate(
-                [mask, np.zeros((pad, mask.shape[1]), mask.dtype)])
-        q_emb = self._encode(index.params, jnp.asarray(toks),
-                             jnp.asarray(mask))[:B]
-        return index.search(q_emb, k=self.k)
+        # fixed (q_rows, L) block: one compiled encode and one compiled
+        # search for every batch size; pad rows are dropped after the top-k
+        pad = ((0, self.q_rows - B), (0, 0))
+        q_emb = self._encode(index.params, jnp.asarray(np.pad(toks, pad)),
+                             jnp.asarray(np.pad(mask, pad)))
+        ids, scores = index.search(q_emb, k=self.k)
+        return ids[:B], scores[:B]

@@ -477,8 +477,9 @@ def test_engine_places_host_params_once(window):
         E.TokenStore.build(queries, max_len=2, chunk=8), stage)
     eng.run(host)
     assert seen and all(seen)
+    scfg = ServeConfig(scoring=ValidationConfig(k=5, batch_size=8))
     index = IndexBuilder(spec, {f"d{i}": t for i, t in enumerate(docs)},
-                         ServeConfig(k=5, batch_size=8)).build(host, 1)
+                         scfg).build(host, 1)
     assert isinstance(index.params["table"], jax.Array)
 
 

@@ -811,6 +811,7 @@ def test_async_validator_extra_protect_and_gc_end_to_end(tmp_path):
     driven by the validator's protect_set keeps the serving checkpoint on
     disk even when its quality rank says delete it."""
     from benchmarks.common import toy_spec, train_toy_dr
+    from repro.core.suite import ValidationConfig
     from repro.data import corpus as corpus_lib
     from repro.serve import IndexBuilder, Promoter, QueryService, ServeConfig
 
@@ -823,7 +824,8 @@ def test_async_validator_extra_protect_and_gc_end_to_end(tmp_path):
         ckpt.save(root, step, {"params": params})
     steps = [s for s, _ in snaps]
 
-    builder = IndexBuilder(spec, ds.corpus, ServeConfig(k=5, batch_size=32))
+    builder = IndexBuilder(spec, ds.corpus, ServeConfig(
+        scoring=ValidationConfig(k=5, batch_size=32)))
     service = QueryService(spec, k=5, max_batch=4)
     target = {"step": steps[0]}
     promoter = Promoter(builder, service, root,

@@ -19,12 +19,14 @@ from benchmarks.common import toy_spec, train_toy_dr
 from repro.ckpt import checkpoint as ckpt
 from repro.control.events import (ACTUATION_KINDS, DECISION_KINDS,
                                   ControlEventLog)
+from repro.core.suite import ValidationConfig
 from repro.data import corpus as corpus_lib
 from repro.serve import (AdmissionController, IndexBuilder, Promoter,
                          QueryService, ServeConfig, ServeOverloaded,
                          replay_swaps)
 
 K = 8
+SERVE_CFG = ServeConfig(scoring=ValidationConfig(k=K, batch_size=32))
 
 
 @pytest.fixture(scope="module")
@@ -40,7 +42,7 @@ def world(tmp_path_factory):
     ckdir = str(base / "ckpts")
     for step, params in snaps:
         ckpt.save(ckdir, step, {"params": params})
-    builder = IndexBuilder(spec, ds.corpus, ServeConfig(k=K, batch_size=32))
+    builder = IndexBuilder(spec, ds.corpus, SERVE_CFG)
     expected = {}
     for step, params in snaps:
         index = builder.build(params, step)
@@ -55,7 +57,7 @@ def world(tmp_path_factory):
 
 def _stack(world, tmp, *, target_fn=None, events=None, **prom_kw):
     ds, spec = world["ds"], world["spec"]
-    builder = IndexBuilder(spec, ds.corpus, ServeConfig(k=K, batch_size=32))
+    builder = IndexBuilder(spec, ds.corpus, SERVE_CFG)
     service = QueryService(spec, k=K, max_batch=4, flush_ms=2.0)
     promoter = Promoter(builder, service, world["ckdir"],
                         target_fn=target_fn, control_events=events,
@@ -228,7 +230,7 @@ def test_admission_rejects_past_bound(world, tmp_path):
     ServeOverloaded instead of queueing unboundedly; capacity frees once
     the batcher drains."""
     ds, spec = world["ds"], world["spec"]
-    builder = IndexBuilder(spec, ds.corpus, ServeConfig(k=K, batch_size=32))
+    builder = IndexBuilder(spec, ds.corpus, SERVE_CFG)
     adm = AdmissionController(max_pending=1)
     service = QueryService(spec, k=K, max_batch=4, flush_ms=2.0,
                            admission=adm)
@@ -332,7 +334,7 @@ def test_token_store_built_once_across_builds(world):
     build) is padded once at construction and shared by every promoted
     checkpoint — only the encode pass reruns."""
     ds, spec = world["ds"], world["spec"]
-    builder = IndexBuilder(spec, ds.corpus, ServeConfig(k=K, batch_size=32))
+    builder = IndexBuilder(spec, ds.corpus, SERVE_CFG)
     store = builder.store
     p1 = ckpt.restore(world["ckdir"], world["steps"][0])[0]["params"]
     p2 = ckpt.restore(world["ckdir"], world["steps"][1])[0]["params"]

@@ -35,20 +35,25 @@ def setup():
     return ds, spec, snaps[-1][1]
 
 
-def _suite(ds, spec, *, score_dtype="f32", mesh=None, impl="xla"):
-    vcfg = ValidationConfig(metrics=("MRR@10",), k=K, batch_size=32,
-                            score_dtype=score_dtype, mesh=mesh, impl=impl)
+def _vcfg(*, score_dtype="f32", mesh=None, impl="xla", chunk_size=None):
+    return ValidationConfig(metrics=("MRR@10",), k=K, batch_size=32,
+                            score_dtype=score_dtype, mesh=mesh, impl=impl,
+                            chunk_size=chunk_size)
+
+
+def _suite(ds, spec, **kw):
     return ValidationSuite(spec, [
-        ValidationTask("default", ds.corpus, ds.queries, ds.qrels)], vcfg)
+        ValidationTask("default", ds.corpus, ds.queries, ds.qrels)],
+        _vcfg(**kw))
 
 
-def _serve_run(ds, spec, params, *, score_dtype="f32", mesh=None,
-               impl="xla", max_batch=5, threaded=True, step=7):
+def _serve_run(ds, spec, params, *, max_batch=5, threaded=True, step=7,
+               **kw):
     """Answer every query through the REAL request path: a started
     micro-batcher with concurrent submits (arbitrary batch packing), or
-    the synchronous ``answer`` path when ``threaded`` is False."""
-    cfg = ServeConfig(k=K, score_dtype=score_dtype, mesh=mesh, impl=impl,
-                      batch_size=32, max_batch=max_batch, flush_ms=2.0)
+    the synchronous ``answer`` path when ``threaded`` is False.  The index
+    scores with the suite's own config (``_vcfg(**kw)``)."""
+    cfg = ServeConfig(max_batch=max_batch, flush_ms=2.0, scoring=_vcfg(**kw))
     builder = IndexBuilder(spec, ds.corpus, cfg)
     service = QueryService(spec, k=K, max_batch=max_batch, flush_ms=2.0)
     service.install(builder.build(params, step))
@@ -163,18 +168,22 @@ def test_serve_parity_property(seed):
     assert srv_scores == val_scores
 
 
-@pytest.mark.parametrize("seed", [11, 29, 47])
-def test_serve_parity_seeded(seed):
+@pytest.mark.parametrize("seed,chunk_size",
+                         [(11, None), (29, None), (47, None), (11, 28)],
+                         ids=["11", "29", "47", "11-chunk28"])
+def test_serve_parity_seeded(seed, chunk_size):
     """Seeded fallback for environments without hypothesis: the same
-    property, pinned."""
+    property, pinned; the last case sets a chunk apart from the encode
+    batch, with a ragged last chunk (120 = 4 x 28 + 8)."""
     ds = corpus_lib.synthetic_retrieval_dataset(seed, n_passages=120,
                                                 n_queries=6)
     spec = toy_spec(ds.vocab)
     _, snaps = train_toy_dr(ds, spec, steps=10, snapshot_every=10)
     params = snaps[-1][1]
-    suite = _suite(ds, spec)
+    suite = _suite(ds, spec, chunk_size=chunk_size)
     val_run, val_scores, _ = suite.engine("default").run(params)
-    srv_run, srv_scores = _serve_run(ds, spec, params, threaded=False)
+    srv_run, srv_scores = _serve_run(ds, spec, params, threaded=False,
+                                     chunk_size=chunk_size)
     assert srv_run == val_run
     assert srv_scores == val_scores
 
@@ -182,9 +191,9 @@ def test_serve_parity_seeded(seed):
 @pytest.mark.slow
 def test_serve_parity_multidevice_padded():
     """8-device sharded serving with a corpus NOT divisible by the mesh:
-    the zero-pad + over-request + host-filter path must still match the
-    single-device answer exactly (tie-free corpus).  Runs in a subprocess
-    with XLA-forced devices, like tests/test_distributed.py."""
+    the ragged last chunk, masked inside the sharded stage, must still
+    match the single-device answer exactly (tie-free corpus).  Runs in a
+    subprocess with XLA-forced devices, like tests/test_distributed.py."""
     import os
     import subprocess
     import sys
@@ -193,6 +202,7 @@ def test_serve_parity_multidevice_padded():
         import numpy as np
         from benchmarks.common import toy_spec, train_toy_dr
         from repro.data import corpus as corpus_lib
+        from repro.core.suite import ValidationConfig
         from repro.serve import IndexBuilder, ServeConfig
         from repro.core.encoder import jitted_encoder
         from repro.data.corpus import pad_batch
@@ -214,10 +224,11 @@ def test_serve_parity_multidevice_padded():
             params, jnp.asarray(toks), jnp.asarray(mask))
         runs = []
         for m in (None, mesh):
-            idx = IndexBuilder(spec, ds.corpus,
-                               ServeConfig(k=10, mesh=m, batch_size=32)
-                               ).build(params, 1)
-            assert (idx.n_pad > 0) == (m is not None)
+            builder = IndexBuilder(spec, ds.corpus, ServeConfig(
+                scoring=ValidationConfig(k=10, mesh=m, batch_size=32)))
+            store = builder.store          # 205 rows: a ragged last chunk
+            assert 0 < store.rows_valid(store.n_chunks - 1) < store.chunk
+            idx = builder.build(params, 1)
             runs.append(idx.search_run(qids, q_emb, k=10))
         assert runs[0] == runs[1], "padded sharded run diverged"
         print("OK")
