@@ -70,7 +70,7 @@ from repro.core.retrieval import (_hierarchical_slot_max,
                                   pad_candidates, rank_candidates, rerank_run,
                                   retrieve_run)
 from repro.data.corpus import Tokens, pad_batch
-from repro.obs import HELD_ASSIGNMENTS_METRIC
+from repro.obs import HELD_ASSIGNMENTS_METRIC, MERGE_SORTS_METRIC
 
 Run = Mapping[str, List[str]]
 Scores = Mapping[str, List[float]]
@@ -569,15 +569,34 @@ class Stage:
         raise NotImplementedError
 
 
+def merge_group(k: int, chunk: int, w: int) -> int:
+    """How many chunks of a ``w``-chunk scan window one top-k merge takes:
+    the largest divisor ``g`` of ``w`` with ``g * chunk <= k``, at least 1.
+
+    A merge sorts the ``k`` carried candidates with the new columns, so a
+    wide carry (k 1,000 against 64-row chunks) is cheaper merged once over
+    ``g`` chunks, and a narrow one (k 10) once per chunk, where one sort
+    over ``k + g * chunk`` columns would cost more than ``g`` over
+    ``k + chunk``."""
+    return max(g for g in range(1, w + 1)
+               if w % g == 0 and (g == 1 or g * chunk <= k))
+
+
 class StreamTopKStage(Stage):
     """Retrieval mode, XLA path: encode a chunk and merge its local top-k into
     the running (Q, k) carry in a single jitted (fused) step.
 
     ``window`` > 1 additionally compiles a ``lax.scan`` over that many chunks
-    so the engine can fold a whole window of chunks per dispatch — same
-    per-chunk math in the same order (parity is preserved bit for bit), but
-    the Python/dispatch overhead amortizes ``window``-fold.  Token staging
-    grows to O(window x chunk x L); embeddings stay O(chunk x D).
+    so the engine can fold a whole window of chunks per dispatch; the
+    Python/dispatch overhead amortizes ``window``-fold.  The window merges
+    every ``g = merge_group(k, chunk, w)`` chunks: an inner scan encodes and
+    scores ``g`` chunks with the per-chunk math, and one sort merges their
+    ``(Q, g x chunk)`` scores into the carry (``g`` = 1 is the per-chunk
+    fold).  The lists are bit for bit those of the per-chunk fold (see
+    :func:`repro.core.retrieval._topk_with_ids`).  Token staging grows to
+    O(window x chunk x L), score staging to O(Q x g x chunk); embeddings
+    stay O(chunk x D).  With a ``telemetry`` attached, each dispatch adds
+    the merges it runs to the ``merge.sorts`` counter.
 
     With ``encode_counted`` (an encoder that also returns the int32 count of
     routed (token, held expert) assignments) the carry gains that count,
@@ -601,8 +620,10 @@ class StreamTopKStage(Stage):
         self.counted = encode_counted is not None
         k_carry = self.k
 
-        def fold(carry, q_emb, params, toks, mask, base, n_valid):
-            run_s, run_i = carry[:2]
+        def scored(q_emb, params, toks, mask, n_valid):
+            """One chunk's masked (Q, chunk) scores, its column indices and
+            the encoder's held count (None without ``encode_counted``)."""
+            held = None
             with jax.named_scope("encode"):
                 if encode_counted is None:
                     emb = encode_fn(params, toks, mask)       # (chunk, D)
@@ -620,6 +641,11 @@ class StreamTopKStage(Stage):
                 else:
                     s = chunk_scores(q_emb, emb, score_dtype)  # (Q, chunk)
                 s = jnp.where((col < n_valid)[None, :], s, -jnp.inf)
+            return s, col, held
+
+        def fold(carry, q_emb, params, toks, mask, base, n_valid):
+            run_s, run_i = carry[:2]
+            s, col, held = scored(q_emb, params, toks, mask, n_valid)
             # one top-k sort over [carry ‖ chunk]: selecting top-k of the union
             # directly is identical to local-top-k-then-merge (top-k of a set
             # equals top-k of carry ∪ top-k(chunk)) but does one sort of
@@ -627,8 +653,27 @@ class StreamTopKStage(Stage):
             with jax.named_scope("merge"):
                 gcol = jnp.broadcast_to((col + base)[None, :], s.shape)
                 merged = _merge_topk(run_s, run_i, s, gcol, k_carry)
-            return merged if encode_counted is None \
-                else merged + (carry[2] + held,)
+            return merged if held is None else merged + (carry[2] + held,)
+
+        def fold_group(carry, q_emb, params, toks_g, mask_g, bases, n_valids):
+            """Score ``g`` chunks, then one sort of width k + g x chunk."""
+            run_s, run_i = carry[:2]
+
+            def body(held, inp):
+                toks, mask, base, n_valid = inp
+                s, col, h = scored(q_emb, params, toks, mask, n_valid)
+                return (held if h is None else (held[0] + h,)), \
+                    (s, col + base)
+
+            held, (s, ids) = jax.lax.scan(body, carry[2:],
+                                          (toks_g, mask_g, bases, n_valids))
+            g, Q, chunk = s.shape
+            with jax.named_scope("merge"):
+                # (g, Q, chunk) -> (Q, g x chunk), the chunks in order
+                s = jnp.moveaxis(s, 0, 1).reshape(Q, g * chunk)
+                ids = jnp.broadcast_to(ids.reshape(1, g * chunk), s.shape)
+                merged = _merge_topk(run_s, run_i, s, ids, k_carry)
+            return merged + held
 
         def fused(params, q_emb, run_s, run_i, toks, mask, base, n_valid,
                   *held):
@@ -637,17 +682,30 @@ class StreamTopKStage(Stage):
 
         def fused_window(params, q_emb, run_s, run_i, toks_w, mask_w,
                          bases, n_valids, *held):
-            def body(carry, inp):
-                toks, mask, base, n_valid = inp
-                return fold(carry, q_emb, params, toks, mask, base,
-                            n_valid), None
-            carry, _ = jax.lax.scan(body, (run_s, run_i) + held,
-                                    (toks_w, mask_w, bases, n_valids))
+            w, chunk = toks_w.shape[:2]
+            g = merge_group(k_carry, chunk, w)
+            if g == 1:
+                def body(carry, inp):
+                    toks, mask, base, n_valid = inp
+                    return fold(carry, q_emb, params, toks, mask, base,
+                                n_valid), None
+                xs = (toks_w, mask_w, bases, n_valids)
+            else:
+                def body(carry, inp):
+                    return fold_group(carry, q_emb, params, *inp), None
+                xs = tuple(x.reshape((w // g, g) + x.shape[1:])
+                           for x in (toks_w, mask_w, bases, n_valids))
+            carry, _ = jax.lax.scan(body, (run_s, run_i) + held, xs)
             return carry
 
         self._fused = jax.jit(fused, donate_argnums=_donate(2, 3))
         self._fused_window = jax.jit(fused_window,
                                      donate_argnums=_donate(2, 3))
+
+    def _count_merges(self, n: int) -> None:
+        tel = self.telemetry
+        if tel is not None:
+            tel.metrics.counter(MERGE_SORTS_METRIC).inc(n)
 
     def init(self, q_emb):
         Q = q_emb.shape[0]
@@ -657,6 +715,7 @@ class StreamTopKStage(Stage):
 
     def step(self, params, q_emb, carry, toks, mask, base, n_valid):
         run_s, run_i, *held = carry
+        self._count_merges(1)
         return self._call(self._fused, params, q_emb, run_s, run_i, toks,
                           mask, jnp.asarray(base, jnp.int32),
                           jnp.asarray(n_valid, jnp.int32), *held)
@@ -665,6 +724,8 @@ class StreamTopKStage(Stage):
                     n_valids):
         """Fold ``window`` chunks in one dispatch (scan inside the jit)."""
         run_s, run_i, *held = carry
+        w, chunk = toks_w.shape[:2]
+        self._count_merges(w // merge_group(self.k, chunk, w))
         return self._call(self._fused_window, params, q_emb, run_s, run_i,
                           toks_w, mask_w, jnp.asarray(bases, jnp.int32),
                           jnp.asarray(n_valids, jnp.int32), *held)

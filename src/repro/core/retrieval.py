@@ -47,7 +47,15 @@ def _topk_with_ids(s, i, k: int):
     the second key.  The one departure is signed zeros: ``lax.top_k``
     ranks ``+0.0`` above ``-0.0``, while here they tie and keep position
     order, as the Pallas merge (``_merge_tile``) has them.  Negation is
-    exact, so the scores come back bit for bit."""
+    exact, so the scores come back bit for bit.
+
+    The ``(-score, id)`` order is total over distinct ids, so top-k under
+    it does not depend on how the candidates are folded:
+    top-k(top-k(A) ∪ B) = top-k(A ∪ B).  That is why a scan window may
+    merge several chunks' scores in one sort (``merge_group`` in
+    ``repro.core.engine``) and return the per-chunk fold's lists bit for
+    bit: each chunk keeps its own scores and ids, and the only equal
+    entries, the padding, are alike."""
     neg, ids = jax.lax.sort((-s, i), dimension=1, num_keys=2,
                             is_stable=False)
     return -neg[:, :k], ids[:, :k]

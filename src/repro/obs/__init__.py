@@ -60,13 +60,15 @@ from repro.obs.trace import LIFECYCLE_STAGES, SpanTracer, read_trace
 __all__ = ["Telemetry", "MetricsRegistry", "SpanTracer", "read_trace",
            "LIFECYCLE_STAGES", "Counter", "Gauge", "Ewma", "Histogram",
            "SCOPES", "hlo_scopes", "live_telemetry", "GC_PAUSE_METRIC",
-           "HELD_ASSIGNMENTS_METRIC"]
+           "HELD_ASSIGNMENTS_METRIC", "MERGE_SORTS_METRIC"]
 
 #: histogram of host garbage-collection pauses, in seconds
 GC_PAUSE_METRIC = "host.gc_pause_s"
 #: histogram of the (token, held expert) assignments an expert trunk's
 #: passage stream computed, one observation per stream (a verdict's corpus)
 HELD_ASSIGNMENTS_METRIC = "moe.held_assignments"
+#: counter of the top-k merges (one sort each) the retrieval stage dispatched
+MERGE_SORTS_METRIC = "merge.sorts"
 ANNOTATION_PREFIX = "repro."
 #: finished spans a Telemetry keeps in memory: a few hundred verdicts' worth
 RECENT_SPANS = 1 << 16

@@ -18,6 +18,7 @@ hand-off order::
     encoded    -> query tower encode, to the embeddings being ready
     staged     -> the wait for one staged token batch (one span per batch)
     dispatch   -> one fused encode -> score -> top-k step dispatched
+                  (counter merge.sorts: the top-k merges it runs)
     drain      -> the wait for the last dispatched step's carry
     finalize   -> the carry to a run, with two children:
                   finalize.fetch (device -> host copy of the carry) and

@@ -103,38 +103,85 @@ def test_stream_topk_bitwise_vs_topk_exact(N, chunk, k):
 
 
 @pytest.mark.parametrize("N,chunk,k,window", [
-    (60, 4, 10, 8),    # 15 chunks: 1 full window + 7-chunk tail
-    (64, 4, 10, 8),    # 16 chunks: 2 full windows exactly
-    (50, 3, 40, 4),    # ragged final chunk + k > chunk, windows engaged
+    (60, 4, 10, 8),    # 15 chunks: windows of 8, 4 and 2 merged in pairs
+    (64, 4, 10, 8),    # 16 chunks: 2 full windows exactly, g 2 of w 8
+    (50, 3, 40, 4),    # ragged final chunk + k > chunk, g = w
+    (150, 4, 37, 8),   # k not a multiple of chunk, ragged, tail windows,
+                       # passages repeat (ties broken by id)
+    (18, 4, 40, 8),    # corpus smaller than k: padding entries in the carry
+    (40, 4, 12, 8),    # g 2: three chunks fit in k but do not divide w
 ])
 def test_stream_topk_window_bitwise_vs_topk_exact(N, chunk, k, window):
-    """The scan-window fast path folds the same per-chunk math in the same
-    order — bit-for-bit equal to both the per-chunk path and topk_exact."""
+    """The scan window merges every ``merge_group`` chunks, and its carry
+    after each dispatch is bit for bit the per-chunk fold's at the same
+    chunk, and in the end ``topk_exact``'s (scores and ids)."""
     params, doc_texts, c_emb, q_emb = _gather_setup(N, Q=5)
     store = E.TokenStore.build(doc_texts, max_len=2, chunk=chunk)
-    stage = E.StreamTopKStage(_gather_encode, k=k, window=window,
-                              query_ids=[f"q{i}" for i in range(5)],
-                              doc_ids=[f"d{i}" for i in range(N)])
-    carry = stage.init(q_emb)
-    ci = 0
-    while ci < store.n_chunks:                    # mirror the engine loop
-        if ci + window <= store.n_chunks:
-            bases = store.chunk * np.arange(ci, ci + window, dtype=np.int32)
+    ids = dict(query_ids=[f"q{i}" for i in range(5)],
+               doc_ids=[f"d{i}" for i in range(N)])
+    stage = E.StreamTopKStage(_gather_encode, k=k, window=window, **ids)
+    per_chunk = E.StreamTopKStage(_gather_encode, k=k, window=1, **ids)
+    carry, ref = stage.init(q_emb), per_chunk.init(q_emb)
+    groups = []
+    for ci, w in E.plan_schedule(store.n_chunks, window):   # as the engine
+        if w > 1:
+            bases = store.chunk * np.arange(ci, ci + w, dtype=np.int32)
             nvs = np.asarray([store.rows_valid(j)
-                              for j in range(ci, ci + window)], np.int32)
+                              for j in range(ci, ci + w)], np.int32)
             carry = stage.step_window(
-                params, q_emb, carry, jnp.asarray(store.tokens[ci:ci + window]),
-                jnp.asarray(store.mask[ci:ci + window]), bases, nvs)
-            ci += window
+                params, q_emb, carry, jnp.asarray(store.tokens[ci:ci + w]),
+                jnp.asarray(store.mask[ci:ci + w]), bases, nvs)
+            groups.append(E.merge_group(stage.k, chunk, w))
         else:
             carry = stage.step(params, q_emb, carry,
                                jnp.asarray(store.tokens[ci]),
                                jnp.asarray(store.mask[ci]),
                                store.chunk * ci, store.rows_valid(ci))
-            ci += 1
+        for j in range(ci, ci + w):
+            ref = per_chunk.step(params, q_emb, ref,
+                                 jnp.asarray(store.tokens[j]),
+                                 jnp.asarray(store.mask[j]),
+                                 store.chunk * j, store.rows_valid(j))
+        np.testing.assert_array_equal(np.asarray(carry[0]),
+                                      np.asarray(ref[0]))
+        np.testing.assert_array_equal(np.asarray(carry[1]),
+                                      np.asarray(ref[1]))
+    assert max(groups) > 1                       # the grouped merge ran
     es, ei = R.topk_exact(q_emb, c_emb, k=k, block=chunk)
     np.testing.assert_array_equal(np.asarray(carry[0]), np.asarray(es))
     np.testing.assert_array_equal(np.asarray(carry[1]), np.asarray(ei))
+
+
+@pytest.mark.parametrize("k,chunk,w,g", [
+    (1000, 64, 8, 8), (10, 64, 8, 1), (10, 64, 1, 1), (40, 4, 8, 8),
+    (12, 4, 8, 2), (3, 4, 8, 1), (24, 4, 6, 6), (20, 4, 6, 3), (8, 4, 7, 1),
+])
+def test_merge_group_is_the_largest_divisor_within_k(k, chunk, w, g):
+    assert E.merge_group(k, chunk, w) == g
+
+
+@pytest.mark.parametrize("k,chunk,window,width", [
+    (40, 4, 8, 40 + 8 * 4),     # k 1,000-like: one sort over the window
+    (10, 4, 8, 10 + 2 * 4),     # 1 < g < w
+    (3, 4, 8, 3 + 4),           # k 10-like: the per-chunk sort
+])
+def test_fused_window_lowers_one_sort_of_the_group_width(k, chunk, window,
+                                                         width):
+    """The window's program holds one sort, over (Q, k + g x chunk)."""
+    import re
+    Q = 5
+    stage = E.StreamTopKStage(_gather_encode, k=k, window=window,
+                              query_ids=[f"q{i}" for i in range(Q)],
+                              doc_ids=[f"d{i}" for i in range(64)])
+    q = jnp.ones((Q, DIM), jnp.float32)
+    run_s, run_i = stage.init(q)
+    text = stage._fused_window.lower(
+        {"table": jnp.ones((VOCAB, DIM), jnp.float32)}, q, run_s, run_i,
+        jnp.ones((window, chunk, 2), jnp.int32),
+        jnp.ones((window, chunk, 2), bool), jnp.zeros(window, jnp.int32),
+        jnp.full(window, chunk, jnp.int32)).compiler_ir("hlo").as_hlo_text()
+    sorts = re.findall(r"= \(f32\[(\d+),(\d+)\][^)]*\) sort\(", text)
+    assert sorts == [(str(Q), str(width))]
 
 
 def test_stream_topk_finalize_reads_the_carry_as_the_old_lists(tmp_path):
@@ -488,11 +535,11 @@ def test_engine_places_host_params_once(window):
 # ---------------------------------------------------------------------------
 
 
-def _observed_engine(telemetry, window=4, n_docs=40):
+def _observed_engine(telemetry, window=4, n_docs=40, k=5):
     spec = EncoderSpec(name="gather", dim=DIM, encode_query=_gather_encode,
                        encode_passage=_gather_encode, init=None,
                        q_max_len=2, p_max_len=2)
-    stage = E.StreamTopKStage(_gather_encode, k=5,
+    stage = E.StreamTopKStage(_gather_encode, k=k,
                               query_ids=[f"q{i}" for i in range(5)],
                               doc_ids=[f"d{i}" for i in range(n_docs)],
                               window=window)
@@ -504,6 +551,22 @@ def _observed_engine(telemetry, window=4, n_docs=40):
     params = {"table": jnp.asarray(np.random.default_rng(0).normal(
         size=(VOCAB, DIM)), jnp.float32)}
     return eng, params
+
+
+@pytest.mark.parametrize("k,window,merges", [
+    (32, 8, 16 // 8),     # g 8: one merge a window
+    (12, 8, 16 // 2),     # g 2
+    (5, 8, 16),           # g 1: one merge a chunk
+    (32, 1, 16),          # no window: one merge a step
+])
+def test_engine_counts_the_merges_it_dispatches(k, window, merges):
+    """``merge.sorts`` adds ``w // g`` a window and 1 a step: 16 chunks
+    of 4 passages, full windows of 8."""
+    from repro.obs import MERGE_SORTS_METRIC, Telemetry
+    tel = Telemetry(None)
+    eng, params = _observed_engine(tel, window=window, n_docs=64, k=k)
+    eng.run(params)
+    assert tel.metrics.get(MERGE_SORTS_METRIC).value == merges
 
 
 def test_engine_without_telemetry_opens_no_span_or_hook(monkeypatch):

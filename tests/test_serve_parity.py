@@ -169,12 +169,15 @@ def test_serve_parity_property(seed):
 
 
 @pytest.mark.parametrize("seed,chunk_size",
-                         [(11, None), (29, None), (47, None), (11, 28)],
-                         ids=["11", "29", "47", "11-chunk28"])
+                         [(11, None), (29, None), (47, None), (11, 28),
+                          (11, 4)],
+                         ids=["11", "29", "47", "11-chunk28", "11-chunk4"])
 def test_serve_parity_seeded(seed, chunk_size):
     """Seeded fallback for environments without hypothesis: the same
-    property, pinned; the last case sets a chunk apart from the encode
-    batch, with a ragged last chunk (120 = 4 x 28 + 8)."""
+    property, pinned; ``11-chunk28`` sets a chunk apart from the encode
+    batch, with a ragged last chunk (120 = 4 x 28 + 8), and ``11-chunk4``
+    a chunk narrow enough against k 10 that both sides merge two chunks
+    at a time, over windows of their own widths (8, 4, 2 against 30)."""
     ds = corpus_lib.synthetic_retrieval_dataset(seed, n_passages=120,
                                                 n_queries=6)
     spec = toy_spec(ds.vocab)

@@ -79,12 +79,16 @@ def test_passage_encoder_compiles(bert, one_chip):
     assert compiled.memory_analysis() is not None
 
 
-@pytest.mark.parametrize("window", [1, 8])
-def test_fused_streaming_step_compiles(bert, one_chip, window):
+@pytest.mark.parametrize("window,Q,k", [(1, 256, 100), (8, 256, 100),
+                                        (8, 6980, 1000)],
+                         ids=["1", "8", "8-k1000"])
+def test_fused_streaming_step_compiles(bert, one_chip, window, Q, k):
     """encode -> score -> top-k merge, the validator's jitted step: chunk
-    64, 256 queries, k 100, one chunk or a scan window of 8."""
+    64, 256 queries, k 100, one chunk or a scan window of 8; and the k
+    1,000 cells' window (6,980 queries), which merges its 8 chunks at
+    once."""
     spec, params = bert
-    Q, k, chunk, L = 256, 100, 64, 128
+    chunk, L = 64, 128
     stage = StreamTopKStage(spec.encode_passage, k=k,
                             query_ids=[f"q{i}" for i in range(Q)],
                             doc_ids=[f"d{i}" for i in range(16384)],
